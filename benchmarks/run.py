@@ -7,6 +7,8 @@ import time
 
 
 def main() -> None:
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     from benchmarks import (ablation_compression, fig2_gpu_training_function,
                             fig3_generalization, fig45_batchsize_policies,
                             fig_dynamics, fig_models, fig_replan, fig_users,

@@ -11,7 +11,7 @@ Two surfaces:
   e.g. an argument donated/committed differently per call, or a
   non-hashable static arg defeating ``lru_cache``.
 * **Jaxpr hygiene** (:func:`audit_jaxpr_hygiene`) — walks a lowered
-  program (recursing into scan/pjit/custom-call sub-jaxprs) and flags
+  program (recursing into scan/jit/custom-call sub-jaxprs) and flags
   (a) 64-bit dtypes anywhere in the program — host planners work in
   float64 and must cross ``engine.host_to_device`` before dispatch —
   and (b) large constants folded into the jaxpr (captured arrays compile
@@ -24,7 +24,7 @@ from collections import Counter
 from typing import Optional
 
 import numpy as np
-from jax.core import ClosedJaxpr
+from jax.extend.core import ClosedJaxpr
 
 from repro.analysis.report import AuditReport, Severity
 

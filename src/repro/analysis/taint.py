@@ -40,7 +40,7 @@ from dataclasses import dataclass, field, replace
 from typing import Optional
 
 import numpy as np
-from jax.core import ClosedJaxpr, Jaxpr, Literal
+from jax.extend.core import ClosedJaxpr, Jaxpr, Literal
 
 from repro.analysis.report import AuditReport, Severity
 
@@ -661,6 +661,27 @@ class _Interp:
         return [AbsVal(digits=tuple(digits),
                        lanes=lanes if digits else None, const=a.const)]
 
+    def _p_split(self, eqn, ins, where):
+        # jnp.split: consecutive slices along one axis, one per output
+        (v, a), = ins
+        axis = eqn.params["axis"]
+        outs = []
+        for ov in eqn.outvars:
+            extent = ov.aval.shape[axis]
+            digits = []
+            lanes = a.lanes
+            for d in a.digits:
+                if d.axis != axis or extent == v.aval.shape[axis]:
+                    digits.append(d)
+                else:
+                    digits.append(Digit(axis, 1, extent))
+                    if not isinstance(lanes, Known):
+                        lanes = VARIANT
+            outs.append(AbsVal(digits=tuple(digits),
+                               lanes=lanes if digits else None,
+                               const=a.const))
+        return outs
+
     def _p_concatenate(self, eqn, ins, where):
         dim = eqn.params["dimension"]
         out_shape = eqn.outvars[0].aval.shape
@@ -948,10 +969,10 @@ class _Interp:
 
     # higher-order -----------------------------------------------------------
 
-    def _p_pjit(self, eqn, ins, where):
+    def _p_jit(self, eqn, ins, where):
         closed = eqn.params["jaxpr"]
         return self.run_jaxpr(closed.jaxpr, [a for _, a in ins],
-                              where + "/pjit")
+                              where + "/jit")
 
     def _p_closed_call(self, eqn, ins, where):
         closed = eqn.params["call_jaxpr"]

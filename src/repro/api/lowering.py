@@ -707,7 +707,7 @@ class TracedBucket:
     trace ledger the compile audit certifies.
     """
     program: str
-    closed: object               # jax.core.ClosedJaxpr
+    closed: object               # jax.extend.core.ClosedJaxpr
     in_labels: list
     out_contracts: dict
     bucket: Bucket

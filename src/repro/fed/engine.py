@@ -62,8 +62,8 @@ class TraceEvent(NamedTuple):
 
     ``kind`` names the program family (``feel`` / ``dev``); ``key`` is the
     ``lru_cache`` key that selects the compiled program (static config);
-    ``signature`` is the flattened (shape, dtype) tuple of the traced
-    arguments.  Two events with identical (kind, key, signature) mean the
+    ``signature`` is the flattened (shape, dtype, sharding) tuple of the
+    traced arguments.  Two events with identical (kind, key, signature) mean the
     same program traced twice for the same abstract inputs — a retrace.
     """
     kind: str
@@ -102,7 +102,9 @@ def _record_trace(kind: str, key: tuple, args) -> None:
     if _TRACES["suspended"]:
         return
     _TRACES["n"] += 1
-    sig = tuple((tuple(a.shape), str(a.dtype))
+    # jit keys its trace cache on each argument's sharding too (a committed
+    # mesh-sharded input traces apart from a plain one of the same shape)
+    sig = tuple((tuple(a.shape), str(a.dtype), str(jax.typeof(a).sharding))
                 for a in jax.tree_util.tree_leaves(args))
     _TRACES["events"].append(TraceEvent(kind=kind, key=key, signature=sig))
 

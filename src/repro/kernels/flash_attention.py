@@ -17,6 +17,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -83,8 +84,6 @@ def flash_attention_bhsd(q, k, v, *, causal: bool = True,
         _flash_kernel, scale=scale, causal=causal, window=window,
         block_q=block_q, block_k=block_k, nk=nk)
 
-    from repro.kernels import pallas_compat as pc
-
     return pl.pallas_call(
         kernel,
         grid=(BH, nq, nk),
@@ -96,11 +95,11 @@ def flash_attention_bhsd(q, k, v, *, causal: bool = True,
         out_specs=pl.BlockSpec((1, block_q, hd), lambda b, qi, ki: (b, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, S, hd), q.dtype),
         scratch_shapes=[
-            pc.VMEM((block_q, hd), jnp.float32),   # acc
-            pc.VMEM((block_q, 1), jnp.float32),    # running max m
-            pc.VMEM((block_q, 1), jnp.float32),    # running denom l
+            pltpu.VMEM((block_q, hd), jnp.float32),   # acc
+            pltpu.VMEM((block_q, 1), jnp.float32),    # running max m
+            pltpu.VMEM((block_q, 1), jnp.float32),    # running denom l
         ],
-        compiler_params=pc.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v)

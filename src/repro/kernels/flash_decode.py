@@ -16,8 +16,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from repro.kernels import pallas_compat as pc
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -85,7 +84,7 @@ def flash_decode_bhd(q, k, v, pos, *, window: Optional[int] = None,
         kernel,
         grid=(BH, ns),
         in_specs=[
-            pl.BlockSpec(memory_space=pc.SMEM),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, 1, hd), lambda b, s: (b, 0, 0)),
             pl.BlockSpec((1, block_s, hd), lambda b, s: (b, s, 0)),
             pl.BlockSpec((1, block_s, hd), lambda b, s: (b, s, 0)),
@@ -93,11 +92,11 @@ def flash_decode_bhd(q, k, v, pos, *, window: Optional[int] = None,
         out_specs=pl.BlockSpec((1, 1, hd), lambda b, s: (b, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, 1, hd), q.dtype),
         scratch_shapes=[
-            pc.VMEM((1, hd), jnp.float32),
-            pc.VMEM((1, 1), jnp.float32),
-            pc.VMEM((1, 1), jnp.float32),
+            pltpu.VMEM((1, hd), jnp.float32),
+            pltpu.VMEM((1, 1), jnp.float32),
+            pltpu.VMEM((1, 1), jnp.float32),
         ],
-        compiler_params=pc.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(jnp.asarray(pos, jnp.int32)[None], q, k, v)

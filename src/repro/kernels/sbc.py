@@ -1,10 +1,17 @@
 """Sparse-binary-compression kernels (TPU Pallas): the paper's uplink
 compression hot-spot (Step 2, [24]) as a two-kernel pipeline.
 
-  * ``sbc_stats``   — tiled reduction: per-block partial sums/counts of
-    positive/negative magnitudes above a threshold (grid over 1-D blocks,
-    scratch accumulators, flushed on the last block).
+  * ``sbc_stats``   — tiled reduction: per-lane partial sums/counts of
+    positive/negative magnitudes above a threshold, accumulated across
+    row blocks in the resident output tile.
   * ``sbc_apply``   — tiled map: binarize survivors to ±mean-magnitude.
+
+Both kernels take the tensor as a lane-dense ``(rows, 128)`` slab and
+their scalars as ``(·, 128)`` rows broadcast across lanes, so every block
+is either ``(block_rows, 128)`` with ``block_rows % 8 == 0`` or a whole
+small operand — the TPU tiling rule holds under any number of leading
+``vmap`` axes (the engine vmaps them over clients and scenario rows), and
+no scalar is ever stored to VMEM.
 
 The global top-k threshold itself stays in XLA (jax.lax.top_k): a sort is
 not a Pallas-shaped problem on TPU — the *bandwidth-bound streaming passes*
@@ -13,86 +20,84 @@ kernels/ops.py vs compression.sbc.sbc_tensor.
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import pallas_compat as pc
+LANES = 128
+# sublane rows of the stats tile: [pos_sum, neg_sum, pos_cnt, neg_cnt, 0…]
+STAT_ROWS = 8
 
 
-def _stats_kernel(x_ref, thr_ref, o_ref, acc_ref, *, nb: int):
-    bi = pl.program_id(0)
-
-    @pl.when(bi == 0)
+def _stats_kernel(x_ref, thr_ref, o_ref):
+    @pl.when(pl.program_id(0) == 0)
     def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        o_ref[...] = jnp.zeros_like(o_ref)
 
-    x = x_ref[...].astype(jnp.float32)
-    thr = thr_ref[0]
+    x = x_ref[...].astype(jnp.float32)            # (block_rows, 128)
     mag = jnp.abs(x)
-    keep = mag >= thr
+    keep = mag >= thr_ref[...]                    # (1, 128) broadcast
     pos = keep & (x > 0)
     neg = keep & (x < 0)
-    acc_ref[0, 0] += jnp.sum(jnp.where(pos, mag, 0.0))
-    acc_ref[0, 1] += jnp.sum(jnp.where(neg, mag, 0.0))
-    acc_ref[0, 2] += jnp.sum(pos.astype(jnp.float32))
-    acc_ref[0, 3] += jnp.sum(neg.astype(jnp.float32))
+    parts = (jnp.sum(jnp.where(pos, mag, 0.0), axis=0, keepdims=True),
+             jnp.sum(jnp.where(neg, mag, 0.0), axis=0, keepdims=True),
+             jnp.sum(pos.astype(jnp.float32), axis=0, keepdims=True),
+             jnp.sum(neg.astype(jnp.float32), axis=0, keepdims=True))
+    row = jax.lax.broadcasted_iota(jnp.int32, o_ref.shape, 0)
+    tile = jnp.zeros(o_ref.shape, jnp.float32)
+    for i, part in enumerate(parts):
+        tile = jnp.where(row == i, part, tile)
+    o_ref[...] += tile
 
-    @pl.when(bi == nb - 1)
-    def _flush():
-        o_ref[...] = acc_ref[...]
 
-
-def sbc_stats(x_flat, thr, *, block: int = 65536, interpret: bool = False):
-    """x_flat: (n,) padded to block multiple; returns (1,4) f32
-    [pos_sum, neg_sum, pos_cnt, neg_cnt]."""
-    n = x_flat.shape[0]
-    assert n % block == 0
-    nb = n // block
+def sbc_stats(x2d, thr_row, *, block_rows: int = 512,
+              interpret: bool = False):
+    """x2d: (rows, 128) with ``rows % block_rows == 0``; thr_row: (1, 128)
+    threshold broadcast across lanes.  Returns the (8, 128) per-lane
+    partials; rows 0-3 summed over lanes are [pos_sum, neg_sum, pos_cnt,
+    neg_cnt]."""
+    rows, lanes = x2d.shape
+    if rows % block_rows:
+        raise ValueError(f"{block_rows=} must divide {rows=}")
     return pl.pallas_call(
-        functools.partial(_stats_kernel, nb=nb),
-        grid=(nb,),
-        in_specs=[
-            pl.BlockSpec((block,), lambda b: (b,)),
-            pl.BlockSpec(memory_space=pc.SMEM),
-        ],
-        out_specs=pl.BlockSpec((1, 4), lambda b: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((1, 4), jnp.float32),
-        scratch_shapes=[pc.VMEM((1, 4), jnp.float32)],
-        compiler_params=pc.CompilerParams(
+        _stats_kernel,
+        grid=(rows // block_rows,),
+        in_specs=[pl.BlockSpec((block_rows, lanes), lambda b: (b, 0)),
+                  pl.BlockSpec((1, lanes), lambda b: (0, 0))],
+        out_specs=pl.BlockSpec((STAT_ROWS, lanes), lambda b: (0, 0)),
+        out_shape=jax.ShapeDtypeStruct((STAT_ROWS, lanes), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(x_flat, thr)
+    )(x2d, thr_row)
 
 
 def _apply_kernel(x_ref, sc_ref, o_ref):
     x = x_ref[...].astype(jnp.float32)
-    thr, val_pos, val_neg = sc_ref[0], sc_ref[1], sc_ref[2]
-    mag = jnp.abs(x)
-    keep = mag >= thr
+    sc = sc_ref[...]                              # (3, 128)
+    thr, val_pos, val_neg = sc[0:1], sc[1:2], sc[2:3]
+    keep = jnp.abs(x) >= thr
     out = jnp.where(keep & (x > 0), val_pos,
                     jnp.where(keep & (x < 0), val_neg, 0.0))
     o_ref[...] = out.astype(o_ref.dtype)
 
 
-def sbc_apply(x_flat, scalars, *, block: int = 65536,
+def sbc_apply(x2d, scalar_rows, *, block_rows: int = 512,
               interpret: bool = False):
-    """scalars: (3,) f32 [thr, val_pos, val_neg] (val for dropped group = 0)."""
-    n = x_flat.shape[0]
-    assert n % block == 0
-    nb = n // block
+    """scalar_rows: (3, 128) rows [thr, val_pos, val_neg], each broadcast
+    across lanes (the value of the dropped sign group is 0)."""
+    rows, lanes = x2d.shape
+    if rows % block_rows:
+        raise ValueError(f"{block_rows=} must divide {rows=}")
     return pl.pallas_call(
         _apply_kernel,
-        grid=(nb,),
-        in_specs=[
-            pl.BlockSpec((block,), lambda b: (b,)),
-            pl.BlockSpec(memory_space=pc.SMEM),
-        ],
-        out_specs=pl.BlockSpec((block,), lambda b: (b,)),
-        out_shape=jax.ShapeDtypeStruct((n,), x_flat.dtype),
-        compiler_params=pc.CompilerParams(
-            dimension_semantics=("arbitrary",)),
+        grid=(rows // block_rows,),
+        in_specs=[pl.BlockSpec((block_rows, lanes), lambda b: (b, 0)),
+                  pl.BlockSpec((3, lanes), lambda b: (0, 0))],
+        out_specs=pl.BlockSpec((block_rows, lanes), lambda b: (b, 0)),
+        out_shape=jax.ShapeDtypeStruct((rows, lanes), x2d.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
         interpret=interpret,
-    )(x_flat, scalars)
+    )(x2d, scalar_rows)

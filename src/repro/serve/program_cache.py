@@ -12,22 +12,19 @@ each admission warm (all of its chunk programs seen before → zero new
 ``TraceEvent``s in the PR-6 ledger, test-enforced) or cold, feeding the
 hit/miss counters ``ServiceStats`` reports.
 
-Persistence has two scopes:
-
-* **process scope** (default): the registry is class-shared, so every
-  service instance in a process sees programs warmed by any other — a
-  restarted service object re-admits known shapes warm because the jit
-  caches it fronts are process-level too.
-* **disk scope** (``persist_dir=``): best-effort enablement of jax's
-  own compilation cache, which persists *compiled XLA executables*
-  across processes.  The key registry stays process-scoped on purpose —
-  in a fresh process a known shape still costs one trace (jax re-traces
-  before consulting the XLA cache), so pre-marking disk-cached keys as
-  warm would break the warm ⇒ zero-``TraceEvent`` contract.
+The registry is class-shared by default, so every service instance in a
+process sees programs warmed by any other — a restarted service object
+re-admits known shapes warm because the jit caches it fronts are
+process-level too.  Compiled executables persist across processes only
+through jax's own compilation cache, placed once per entry point by
+:func:`repro.launch.compile_cache.use_compile_cache`; a fresh process
+still re-traces a known shape before consulting it, so the registry
+deliberately stays process-scoped (pre-marking disk-cached keys warm
+would break the warm ⇒ zero-``TraceEvent`` contract).
 """
 from __future__ import annotations
 
-from typing import Dict, Iterable, Optional, Tuple
+from typing import Dict, Iterable, Tuple
 
 __all__ = ["ProgramCache"]
 
@@ -37,25 +34,11 @@ class ProgramCache:
 
     _SHARED: Dict[tuple, int] = {}
 
-    def __init__(self, shared: bool = True,
-                 persist_dir: Optional[str] = None):
+    def __init__(self, shared: bool = True):
         self._seen: Dict[tuple, int] = (ProgramCache._SHARED if shared
                                         else {})
         self.hits = 0
         self.misses = 0
-        if persist_dir is not None:
-            self._enable_disk_cache(persist_dir)
-
-    @staticmethod
-    def _enable_disk_cache(path: str) -> bool:
-        """Point jax's compilation cache at ``path`` (best-effort: older
-        jax builds without the knob are tolerated silently)."""
-        try:
-            import jax
-            jax.config.update("jax_compilation_cache_dir", str(path))
-            return True
-        except Exception:                                 # noqa: BLE001
-            return False
 
     def __len__(self) -> int:
         return len(self._seen)

@@ -247,8 +247,7 @@ def test_trace_ledger_flags_retrace_and_count():
 
 
 def test_hygiene_flags_x64_leak():
-    from jax.experimental import enable_x64
-    with enable_x64():
+    with jax.enable_x64(True):
         closed = jax.make_jaxpr(lambda x: x * 2.0)(np.float64(1.0))
     report = compile_audit.audit_jaxpr_hygiene(closed, program="x64")
     assert not report.ok
@@ -446,6 +445,19 @@ def test_prim_structural_ops_preserve_lanes():
     _ok(lambda x, m: jnp.concatenate(
             [x * m[:, None], x * m[:, None]], axis=1).sum(axis=0),
         (_X, _M), _XM_LABELS)
+
+
+def test_prim_split_transfers_like_slices():
+    """``jnp.split`` (the ``split`` primitive) keeps the lane facts off the
+    split axis; cut along the user axis, only Known(0) lanes survive."""
+    _ok(lambda x, m: sum(p.sum(axis=0)
+                         for p in jnp.split(x * m[:, None], 3, axis=1)),
+        (_X, _M), _XM_LABELS)
+    _ok(lambda x, m: sum(p.sum(axis=0)
+                         for p in jnp.split(x * m[:, None], 2, axis=0)),
+        (_X, _M), _XM_LABELS)
+    _fails(lambda x, m: sum(p.sum(axis=0) for p in jnp.split(x, 2, axis=0)),
+           (_X, _M), _XM_LABELS, "taint.unmasked-reduction")
 
 
 def test_prim_dynamic_update_slice():

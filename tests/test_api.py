@@ -543,6 +543,20 @@ def test_mesh_one_device_fallback(dataset, fleet):
     np.testing.assert_allclose(plain.accs, sharded.accs, atol=1e-6)
 
 
+def test_mesh_sharded_trace_is_not_a_retrace(dataset, fleet):
+    """jit traces a mesh-sharded bucket apart from the plain one of the
+    same shapes; the ledger's signature carries the sharding, so the pair
+    is two programs and not a retrace (7 rows: shapes no other test
+    traces)."""
+    data, test = dataset
+    specs = [_spec(fleet, partition="iid", policy="proposed",
+                   seeds=tuple(range(7)))]
+    with no_retrace(expect=2):
+        Experiment(data, test, specs).run(periods=5)
+        Experiment(data, test, specs).run(
+            periods=5, executor=MeshExecutor(make_batch_mesh(1)))
+
+
 def test_mesh_executor_rejects_non_batch_mesh(dataset, fleet):
     """Executors validate their mesh up front: a mesh without a 'batch'
     axis fails fast instead of deep inside device_put."""

@@ -225,8 +225,9 @@ def test_sbc_pipeline_vs_oracle(n, ratio, block):
 
 def test_sbc_stats_kernel():
     x = jnp.asarray([3.0, -4.0, 1.0, -0.5, 2.5, -2.5, 0.1, 0.0])
-    thr = jnp.asarray([2.0])
-    st = sbc_stats(x, thr, block=8, interpret=True)[0]
+    slab = jnp.pad(x, (0, 16 * 128 - 8)).reshape(16, 128)
+    thr = jnp.full((1, 128), 2.0)
+    st = sbc_stats(slab, thr, block_rows=8, interpret=True)[:4].sum(axis=1)
     assert float(st[0]) == pytest.approx(5.5)    # pos magnitudes 3 + 2.5
     assert float(st[1]) == pytest.approx(6.5)    # neg magnitudes 4 + 2.5
     assert float(st[2]) == 2 and float(st[3]) == 2
@@ -234,10 +235,14 @@ def test_sbc_stats_kernel():
 
 def test_sbc_apply_kernel():
     x = jnp.asarray([3.0, -4.0, 1.0, -0.5, 2.5, -2.5, 0.1, 0.0])
+    slab = jnp.pad(x, (0, 16 * 128 - 8)).reshape(16, 128)
     scal = jnp.asarray([2.0, 0.0, -3.25])        # thr, vpos(drop), vneg
-    out = sbc_apply(x, scal, block=8, interpret=True)
+    out = sbc_apply(slab, jnp.broadcast_to(scal[:, None], (3, 128)),
+                    block_rows=8, interpret=True)
     np.testing.assert_allclose(
-        np.asarray(out), [0, -3.25, 0, 0, 0, -3.25, 0, 0], atol=1e-6)
+        np.asarray(out).reshape(-1)[:8], [0, -3.25, 0, 0, 0, -3.25, 0, 0],
+        atol=1e-6)
+    assert not np.asarray(out).reshape(-1)[8:].any()
 
 
 def test_sbc_edge_semantics():
@@ -279,3 +284,107 @@ def test_sbc_kernel_composition_matches_oracle(n, ratio, seed):
     out = ops.sbc_compress(g, ratio, block=256, interpret=True)
     want = ref.sbc_ref(g, ratio)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=1e-6)
+
+
+# ---------------------------------------------------------------------------
+# the chip path's repairs: custom VJPs, family-shape tiling, per-client vmap
+# ---------------------------------------------------------------------------
+
+
+def _family_ssd_inputs(lead, s=16):
+    """SSD operands at the mamba2 family's per-head dims (hidden 256:
+    P=8, G=1, N=16, chunk 4), with 8 of its 64 heads to keep interpret
+    mode quick; ``lead`` are the leading batch dims."""
+    from repro.fed.model_engine import family_arch
+    ssm = family_arch("mamba2", 256, 3).ssm
+    h, p, g, n = 8, ssm.head_dim, ssm.n_groups, ssm.d_state
+    ks = [jax.random.fold_in(KEY, 10 + i) for i in range(5)]
+    x = jax.random.normal(ks[0], lead + (s, h, p))
+    dt = jax.nn.softplus(jax.random.normal(ks[1], lead + (s, h)))
+    A = -jnp.exp(jax.random.normal(ks[2], (h,)) * 0.3)
+    Bm = jax.random.normal(ks[3], lead + (s, g, n)) * 0.5
+    Cm = jax.random.normal(ks[4], lead + (s, g, n)) * 0.5
+    return (x, dt, A, Bm, Cm), ssm.chunk
+
+
+def test_flash_attention_custom_vjp_matches_reference_grad():
+    """The kernel's forward + the oracle's recomputed VJP give the value and
+    gradients of plain GQA attention (the model's naive path)."""
+    from repro.models.attention import attend_naive
+    B, S, Hq, Hkv, hd = 2, 16, 4, 2, 64
+    q = jax.random.normal(jax.random.fold_in(KEY, 0), (B, S, Hq, hd))
+    k = jax.random.normal(jax.random.fold_in(KEY, 1), (B, S, Hkv, hd))
+    v = jax.random.normal(jax.random.fold_in(KEY, 2), (B, S, Hkv, hd))
+    ct = jax.random.normal(jax.random.fold_in(KEY, 3), (B, S, Hq, hd))
+    pos = jnp.arange(S)
+
+    def kern(q, k, v):
+        return jnp.sum(ops.flash_attention(q, k, v, interpret=True) * ct)
+
+    def naive(q, k, v):
+        return jnp.sum(attend_naive(q, k, v, pos, pos) * ct)
+
+    got_v, got_g = jax.value_and_grad(kern, argnums=(0, 1, 2))(q, k, v)
+    want_v, want_g = jax.value_and_grad(naive, argnums=(0, 1, 2))(q, k, v)
+    np.testing.assert_allclose(float(got_v), float(want_v), rtol=2e-5)
+    for got, want in zip(got_g, want_g):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   atol=2e-5, rtol=2e-5)
+
+
+def test_ssd_custom_vjp_matches_reference_grad():
+    """``ops.ssd`` differentiates (kernel forward, reference VJP) to the
+    gradients of ``ssd_reference`` in every operand, A included."""
+    args, chunk = _family_ssd_inputs((2,))
+    ct = jax.random.normal(jax.random.fold_in(KEY, 20), args[0].shape)
+
+    def kern(*a):
+        return jnp.sum(ops.ssd(*a, chunk=chunk, interpret=True) * ct)
+
+    def oracle(*a):
+        return jnp.sum(ref.ssd_ref(*a, chunk=chunk) * ct)
+
+    argnums = tuple(range(5))
+    got_v, got_g = jax.value_and_grad(kern, argnums=argnums)(*args)
+    want_v, want_g = jax.value_and_grad(oracle, argnums=argnums)(*args)
+    np.testing.assert_allclose(float(got_v), float(want_v), rtol=1e-4)
+    for got, want in zip(got_g, want_g):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   atol=1e-4, rtol=1e-4)
+
+
+@pytest.mark.parametrize("lead", [(2,), (2, 3)], ids=["clients", "rows-clients"])
+def test_ssd_scan_family_chunk_under_vmap(lead):
+    """The head-major ``ssd_scan`` at the family chunk matches the oracle
+    when vmapped the way the engine runs it (over clients, then rows)."""
+    args, chunk = _family_ssd_inputs(lead)
+    A = args[2]
+    kern = lambda x, dt, Bm, Cm: ssd_scan(x, dt, A, Bm, Cm,  # noqa: E731
+                                          chunk=chunk, interpret=True)
+    oracle = lambda x, dt, Bm, Cm: ref.ssd_ref(x, dt, A, Bm, Cm,  # noqa: E731
+                                               chunk=chunk)
+    for _ in range(len(lead) - 1):
+        kern, oracle = jax.vmap(kern), jax.vmap(oracle)
+    x, dt, _, Bm, Cm = args
+    np.testing.assert_allclose(np.asarray(kern(x, dt, Bm, Cm)),
+                               np.asarray(oracle(x, dt, Bm, Cm)),
+                               atol=3e-4, rtol=3e-4)
+
+
+@pytest.mark.parametrize("shape,lead", [((6, 256, 512), 1),
+                                        ((2, 6, 40), 2),
+                                        ((6, 3), 1)],
+                         ids=["clients", "rows-clients", "tiny-leaf"])
+def test_sbc_kernels_vmapped_match_oracle_per_row(shape, lead):
+    """The two SBC kernels vmapped over clients (and scenario rows) give
+    each client's leaf exactly its own ``sbc_tensor(exact=True)``."""
+    g = jax.random.normal(jax.random.fold_in(KEY, 30), shape) * 2.0
+    kern = lambda t: ops.sbc_compress(t, 0.005, interpret=True)  # noqa: E731
+    for _ in range(lead):
+        kern = jax.vmap(kern)
+    leaves = np.asarray(g).reshape((-1,) + shape[lead:])
+    outs = np.asarray(kern(g)).reshape(leaves.shape)
+    for leaf, out in zip(leaves, outs):
+        want = np.asarray(ref.sbc_ref(jnp.asarray(leaf), 0.005, exact=True))
+        np.testing.assert_allclose(out, want, atol=1e-6)
+        np.testing.assert_array_equal(out != 0, want != 0)
