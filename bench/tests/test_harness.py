@@ -1,0 +1,102 @@
+"""Cell discovery by name, the client-period count, and the runs that
+must fail without printing a result."""
+import json
+import os
+import shutil
+import subprocess
+import sys
+
+import pytest
+
+import workload
+from conftest import BENCH, ROOT, TINY, TINY_TRAFFIC, add_cell
+
+
+def test_every_workload_resolves():
+    bench = workload.load_benchmark(ROOT)
+    for w in bench["workloads"]:
+        cell = workload.find_cell(ROOT, w["name"])
+        assert cell.chips == w["chips"]
+        assert {m["name"] for m in cell.end_to_end} >= {"setup_s"}
+        assert cell.per_layer
+
+
+def test_new_files_add_a_cell(tmp_path):
+    """A cell is files plus BENCHMARK.json entries: no code is edited."""
+    shutil.copy(ROOT / "BENCHMARK.json", tmp_path)
+    shutil.copytree(BENCH, tmp_path / "bench",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    tr = json.loads((BENCH / "traffic" / "fig45_gpu6.json").read_text())
+    tr.update(partitions=["iid"], policies=["full"], periods=7)
+    cfg = json.loads((BENCH / "configs" / "feel_mlp.json").read_text())
+    add_cell(tmp_path, "feel_mlp.gpu6_iid_full", cfg, tr,
+             {"loss_gap": 1, "ledger_faults": 0})
+    with pytest.raises(workload.CellError):
+        workload.find_cell(ROOT, "feel_mlp.gpu6_iid_full")
+    cell = workload.find_cell(tmp_path, "feel_mlp.gpu6_iid_full",
+                              tmp_path / "bench")
+    assert cell.traffic["periods"] == 7
+    assert cell.config["model_family"] == "feel_mlp"
+    specs = workload.make_specs(cell.config, cell.traffic, 3)
+    assert [(s.partition, s.policy, s.k) for s in specs] == [
+        ("iid", "full", 6)]
+
+
+def test_seeds_are_large_and_repeatable():
+    a = workload.derived_seeds(2**33 + 7, 4, workload.STREAM_ROWS)
+    assert a == workload.derived_seeds(2**33 + 7, 4, workload.STREAM_ROWS)
+    assert a != workload.derived_seeds(2**33 + 8, 4, workload.STREAM_ROWS)
+    assert all(0 <= s < 2**31 for s in a)
+
+
+@pytest.mark.parametrize("sampling,expect", [(None, 8 * 3),
+                                             ({"size": 3}, 3 * 3)])
+def test_client_periods_count_participants_only(tiny_root, sampling,
+                                                expect):
+    """K=8 clients, 3 periods: every lane counts under full
+    participation; with a cohort of 3, only the 3 participants do."""
+    root = tiny_root("feel_mlp.fig45_gpu6")
+    cfg = json.loads((BENCH / "configs" / "feel_mlp.json").read_text())
+    cfg.update(TINY["feel_mlp"])
+    tr = json.loads((BENCH / "traffic" / "cohort512.json").read_text())
+    tr.update(TINY_TRAFFIC, sampling=sampling, fleet={
+        "name": "cpu8", "k": 8, "tiers": [
+            {"kind": "cpu", "f_cpu": 0.7e9}, {"kind": "cpu",
+                                              "f_cpu": 2.1e9}]})
+    add_cell(root, "feel_mlp.count", cfg, tr, {"ledger_faults": 0})
+    from repro.api import Experiment
+    cell = workload.find_cell(root, "feel_mlp.count", root / "bench")
+    train, test = workload.make_data(cell.config, 11)
+    spans = workload.Spans()
+    Experiment(train, test, workload.make_specs(
+        cell.config, cell.traffic, 11)).run(
+            3, executor=workload.make_executor(cell.traffic["executor"]))
+    got = workload.call_counts(spans.records)
+    assert got["client_periods"] == expect
+    assert got["periods"] == 3
+    assert spans.span_s["plan_bucket"] > 0
+
+
+def _run(cwd, env_extra):
+    env = dict(os.environ, **env_extra)
+    return subprocess.run(
+        [sys.executable, "bench/run.py", "--workload",
+         "feel_mlp.fig45_gpu6", "--seed", "1", "--seconds", "1",
+         "--trace", "0"], cwd=cwd, env=env, capture_output=True,
+        text=True, timeout=300)
+
+
+def test_no_tpu_fails_without_result():
+    p = _run(ROOT, {"JAX_PLATFORMS": "cpu"})
+    assert p.returncode == 3
+    assert p.stdout.strip() == ""
+    assert "needs a TPU" in p.stderr
+
+
+def test_benchmark_files_alone_fail(tmp_path):
+    shutil.copy(ROOT / "BENCHMARK.json", tmp_path)
+    shutil.copytree(BENCH, tmp_path / "bench",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    p = _run(tmp_path, {"JAX_PLATFORMS": "cpu"})
+    assert p.returncode == 2
+    assert p.stdout.strip() == ""
