@@ -26,6 +26,7 @@ from typing import Iterator, List, Optional, Sequence
 
 import numpy as np
 
+from repro import obs
 from repro.api.executor import Executor, SerialExecutor
 from repro.api.lowering import Bucket, group_rows
 from repro.api.results import (COORD_NAMES, Results, ResultsBuilder,
@@ -96,7 +97,8 @@ class Experiment:
         for builder in self._collected(periods, executor, replan,
                                        bands=bands):
             pass
-        res = builder.build()
+        with obs.span("repro.run.results"):
+            res = builder.build()
         if audit:
             report = self._audit(periods, replan, mark, bands=bands)
             res = _dc_replace(res, audit=report)
@@ -144,22 +146,26 @@ class Experiment:
                    ) -> Iterator[ResultsBuilder]:
         """Drive the executor, yielding the builder after each bucket
         lands (``run`` assembles once at the end; ``stream`` snapshots a
-        partial per yield)."""
-        buckets = self.lower(replan=replan, bands=bands)
-        if not buckets:
-            raise ValueError("Experiment has no specs")
-        if executor is None:
-            executor = SerialExecutor()
-        builder = ResultsBuilder(coords=self._coords(buckets),
-                                 n_rows=self._n_rows(buckets),
-                                 n_buckets=len(buckets))
+        partial per yield).  Grouping and result assembly run as the
+        ``repro.run.group`` and ``repro.run.results`` spans."""
+        with obs.span("repro.run.group"):
+            buckets = self.lower(replan=replan, bands=bands)
+            if not buckets:
+                raise ValueError("Experiment has no specs")
+            if executor is None:
+                executor = SerialExecutor()
+            builder = ResultsBuilder(coords=self._coords(buckets),
+                                     n_rows=self._n_rows(buckets),
+                                     n_buckets=len(buckets))
         for bucket, (bl, ba, bt, bg) in executor.execute(
                 buckets, self.data, self.test, periods):
-            idx = np.array([i for row in bucket.rows
-                            for i in row.indices], np.int64)
-            take = np.array([j for j, row in enumerate(bucket.rows)
-                             for _ in row.indices], np.int64)
-            builder.add_rows(idx, bl[take], ba[take], bt[take], bg[take])
+            with obs.span("repro.run.results"):
+                idx = np.array([i for row in bucket.rows
+                                for i in row.indices], np.int64)
+                take = np.array([j for j, row in enumerate(bucket.rows)
+                                 for _ in row.indices], np.int64)
+                builder.add_rows(idx, bl[take], ba[take], bt[take],
+                                 bg[take])
             yield builder
 
     @staticmethod

@@ -72,6 +72,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.api.spec import ScenarioSpec
 from repro.channels.model import Cell
 from repro.core.scheduler import (DevScheduler, FeelScheduler,
@@ -291,6 +292,7 @@ class BucketPlan:
     times: np.ndarray            # (n, P) cumulative simulated seconds
     global_batch: np.ndarray     # (n, P) int64
     payload: dict
+    ids: dict = field(default_factory=dict)   # span ids: bucket, chunk
 
 
 @dataclass
@@ -312,6 +314,7 @@ class BucketHandle:
     decays: object = None        # (n+pad, P) device array (feel only)
     state: object = None         # engine.EngineState after this chunk
     energy: object = None        # (n, P, k_pad) host joules ledger, or None
+    ids: dict = field(default_factory=dict)   # span ids: bucket, chunk
 
 
 # ---------------------------------------------------------------------------
@@ -321,7 +324,44 @@ class BucketHandle:
 # ---------------------------------------------------------------------------
 
 
-class _FeelPlanner:
+def lane_counts(plan: BucketPlan) -> dict:
+    """The ``repro.plan`` span's counters: ``lanes`` computed (rows ×
+    periods × k_pad) and ``lanes_used``, the (client, period) pairs
+    active with B_k > 0 (every active dev-family lane trains)."""
+    n, periods = plan.times.shape
+    k_pad = plan.bucket.k_pad
+    active = np.asarray(plan.payload["active"]) > 0
+    if active.ndim == 2:
+        active = active[:, None, :]
+    if plan.bucket.kind == "feel":
+        batch = np.stack([s.batch for s in plan.payload["schedules"]])
+        used = active & (batch > 0)
+    else:
+        used = np.broadcast_to(active, (n, periods, k_pad))
+    return {"rows": n, "periods": periods, "k_pad": k_pad,
+            "lanes": n * periods * k_pad, "lanes_used": int(used.sum())}
+
+
+class _Planner:
+    """Every ``plan()`` of a bucket run is one ``repro.plan`` span; its
+    ids (the bucket serial :func:`_make_planner` gives and the chunk
+    index) ride the plan into the dispatch and collect spans."""
+
+    serial = 0
+    chunk = 0
+
+    def plan(self, periods: int, warm_start: bool = False) -> BucketPlan:
+        ids = {"bucket": self.serial, "chunk": self.chunk}
+        with obs.span("repro.plan", **ids) as sp:
+            plan = self._plan(periods, warm_start)
+            if sp.on:
+                sp.stat(**lane_counts(plan))
+        plan.ids = ids
+        self.chunk += 1
+        return plan
+
+
+class _FeelPlanner(_Planner):
     """Host planning state for one FEEL bucket, resumable chunk by chunk.
 
     ``per_row=False`` (open loop): one scheduler — and one planned
@@ -376,7 +416,7 @@ class _FeelPlanner:
         # every plan() once ξ feedback has landed)
         self._tau = rows[0].spec.local_steps
 
-    def plan(self, periods: int, warm_start: bool = False) -> BucketPlan:
+    def _plan(self, periods: int, warm_start: bool) -> BucketPlan:
         rows = self.bucket.rows
         spec0 = rows[0].spec
         tau = None
@@ -394,6 +434,16 @@ class _FeelPlanner:
         planned = plan_horizons_batch(self.schedulers, periods,
                                       warm_start=warm_start,
                                       closed_loop=self.per_row)
+        with obs.span("repro.plan.schedule") as sp:
+            plan = self._schedule(planned, periods, tau)
+            if sp.on:
+                sp.stat(rows=len(rows))
+        return plan
+
+    def _schedule(self, planned, periods: int, tau) -> BucketPlan:
+        """Every row's sample schedule from its planned horizon, padded to
+        the bucket's K, and the device payload around them."""
+        rows = self.bucket.rows
         # per-row planning runs at the row's TRUE fleet size (identical
         # rng streams and ledgers to a solo run); only the finished
         # schedules are zero-padded to the bucket's K so one program fits
@@ -458,7 +508,7 @@ class _FeelPlanner:
             self.schedulers[i].observe_series(decays[i], global_batch[i])
 
 
-class _DevPlanner:
+class _DevPlanner(_Planner):
     """Host planning state for one dev-family bucket (chunk-resumable;
     no ξ loop — ``observe`` does not exist by design)."""
 
@@ -481,7 +531,7 @@ class _DevPlanner:
             for r in rows]
         self._offsets = np.zeros(len(rows))
 
-    def plan(self, periods: int, warm_start: bool = False) -> BucketPlan:
+    def _plan(self, periods: int, warm_start: bool) -> BucketPlan:
         rows = self.bucket.rows
         k_pad = self.bucket.k_pad
         horizons = []
@@ -492,9 +542,12 @@ class _DevPlanner:
         n = len(rows)
         # rows plan at their true K; pad idx user rows with index 0 (the
         # active mask keeps those devices out of every parameter average)
-        idx = np.zeros((n, periods, k_pad, self.batch), np.int64)
-        for i, (r, h) in enumerate(zip(rows, horizons)):
-            idx[i, :, :r.spec.k] = h.idx
+        with obs.span("repro.plan.schedule") as sp:
+            idx = np.zeros((n, periods, k_pad, self.batch), np.int64)
+            for i, (r, h) in enumerate(zip(rows, horizons)):
+                idx[i, :, :r.spec.k] = h.idx
+            if sp.on:
+                sp.stat(rows=n)
         active = self.bucket.active_mask()
         if any(h.participation is not None for h in horizons):
             active = np.repeat(active[:, None, :], periods, axis=1)
@@ -521,8 +574,14 @@ class _DevPlanner:
 
 
 def _make_planner(bucket: Bucket, data, per_row: bool = False):
+    """A fresh planner with a new bucket serial; building it (partitions,
+    schedulers, batchers) is the ``repro.plan.setup`` span."""
     cls = _FeelPlanner if bucket.kind == "feel" else _DevPlanner
-    return cls(bucket, data, per_row=per_row)
+    serial = obs.next_bucket()
+    with obs.span("repro.plan.setup", bucket=serial):
+        planner = cls(bucket, data, per_row=per_row)
+    planner.serial = serial
+    return planner
 
 
 def plan_bucket(bucket: Bucket, data, periods: int) -> BucketPlan:
@@ -606,19 +665,21 @@ def _dispatch_feel(plan: BucketPlan, data, test, mesh,
     n = len(rows)
     pad = 0 if mesh is None else pad_batch(n, mesh)
     if state is None:
-        params0 = _init_params_batch(rows, plan.input_dim)
-        residual0 = tree_map(
-            lambda p: jnp.zeros((p.shape[0], k_pad) + p.shape[1:], p.dtype),
-            params0)
-        if member is not None:
-            # every edge replica starts from the row's global init
-            params0 = tree_map(
-                lambda a: jnp.broadcast_to(
-                    a[:, None], (a.shape[0], member.shape[1]) + a.shape[1:]),
-                params0)
-        if pad:
-            params0, residual0 = _pad_rows((params0, residual0), n, pad)
-        state = engine.EngineState(params=params0, residual=residual0)
+        with obs.span("repro.dispatch.init"):
+            params0 = _init_params_batch(rows, plan.input_dim)
+            residual0 = tree_map(
+                lambda p: jnp.zeros((p.shape[0], k_pad) + p.shape[1:],
+                                    p.dtype), params0)
+            if member is not None:
+                # every edge replica starts from the row's global init
+                params0 = tree_map(
+                    lambda a: jnp.broadcast_to(
+                        a[:, None],
+                        (a.shape[0], member.shape[1]) + a.shape[1:]),
+                    params0)
+            if pad:
+                params0, residual0 = _pad_rows((params0, residual0), n, pad)
+            state = engine.EngineState(params=params0, residual=residual0)
     if pad:
         active = _pad_rows(active, n, pad)
         schedules = [schedules[i % n] for i in range(n + pad)]
@@ -646,7 +707,7 @@ def _dispatch_feel(plan: BucketPlan, data, test, mesh,
     return BucketHandle(bucket=plan.bucket, losses=losses, accs=accs,
                         times=plan.times, global_batch=plan.global_batch,
                         decays=decays, state=state,
-                        energy=plan.payload.get("energy"))
+                        energy=plan.payload.get("energy"), ids=plan.ids)
 
 
 def _dispatch_dev(plan: BucketPlan, data, test, mesh,
@@ -660,13 +721,14 @@ def _dispatch_dev(plan: BucketPlan, data, test, mesh,
     n = len(rows)
     pad = 0 if mesh is None else pad_batch(n, mesh)
     if state is None:
-        p0 = _init_params_batch(rows, plan.input_dim)
-        dev_params0 = tree_map(
-            lambda a: jnp.broadcast_to(
-                a[:, None], (a.shape[0], k_pad) + a.shape[1:]), p0)
-        if pad:
-            dev_params0 = _pad_rows(dev_params0, n, pad)
-        state = engine.EngineState(params=dev_params0)
+        with obs.span("repro.dispatch.init"):
+            p0 = _init_params_batch(rows, plan.input_dim)
+            dev_params0 = tree_map(
+                lambda a: jnp.broadcast_to(
+                    a[:, None], (a.shape[0], k_pad) + a.shape[1:]), p0)
+            if pad:
+                dev_params0 = _pad_rows(dev_params0, n, pad)
+            state = engine.EngineState(params=dev_params0)
     if pad:
         idx, lr, active = _pad_rows((idx, lr, active), n, pad)
     state, (losses, accs) = engine.resume_dev_trajectory_batch(
@@ -674,7 +736,7 @@ def _dispatch_dev(plan: BucketPlan, data, test, mesh,
         average=(spec0.scheme == "model_fl"), mesh=mesh, active=active)
     return BucketHandle(bucket=plan.bucket, losses=losses, accs=accs,
                         times=plan.times, global_batch=plan.global_batch,
-                        state=state)
+                        state=state, ids=plan.ids)
 
 
 def dispatch_bucket(plan: BucketPlan, data, test, mesh=None,
@@ -683,10 +745,13 @@ def dispatch_bucket(plan: BucketPlan, data, test, mesh=None,
     with in-flight device values (jax dispatch is asynchronous).
 
     ``state`` resumes from a previous chunk's engine carry (chunked
-    horizons); ``None`` initializes a fresh trajectory."""
+    horizons); ``None`` initializes a fresh trajectory.  Runs as one
+    ``repro.dispatch`` span (children ``init``, ``upload``, ``enqueue``)
+    carrying the plan's ids."""
     dispatcher = (_dispatch_feel if plan.bucket.kind == "feel"
                   else _dispatch_dev)
-    return dispatcher(plan, data, test, mesh, state=state)
+    with obs.span("repro.dispatch", **plan.ids):
+        return dispatcher(plan, data, test, mesh, state=state)
 
 
 # ---------------------------------------------------------------------------
@@ -888,8 +953,9 @@ def collect_bucket(handle: BucketHandle):
     ``(losses, accs, times, global_batch)`` — (n, P) host arrays, one row
     per computed row (fan out duplicates via ``Row.indices``)."""
     n = len(handle.bucket.rows)
-    losses = np.asarray(handle.losses)[:n]
-    accs = np.asarray(handle.accs)[:n]
+    with obs.span("repro.collect.wait", **handle.ids):
+        losses = np.asarray(handle.losses)[:n]
+        accs = np.asarray(handle.accs)[:n]
     return losses, accs, handle.times, handle.global_batch
 
 
@@ -990,10 +1056,12 @@ class BucketRun:
             raise RuntimeError("no chunk in flight to collect")
         p_c, handle = self._pending.popleft()
         n = len(self.bucket.rows)
-        losses = np.asarray(handle.losses)[:n]
-        accs = np.asarray(handle.accs)[:n]
+        with obs.span("repro.collect.wait", **handle.ids):
+            losses = np.asarray(handle.losses)[:n]
+            accs = np.asarray(handle.accs)[:n]
+            if self.closed_loop:
+                decays = np.asarray(handle.decays)[:n]
         if self.closed_loop:
-            decays = np.asarray(handle.decays)[:n]
             self._decays.append(decays)
             self._planner.observe(decays, handle.global_batch)
         chunk = (losses, accs, handle.times, handle.global_batch)

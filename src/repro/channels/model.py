@@ -12,6 +12,8 @@ from dataclasses import dataclass
 
 import numpy as np
 
+from repro import obs
+
 
 @dataclass(frozen=True)
 class CellConfig:
@@ -59,9 +61,13 @@ class Cell:
         p_rx_dbm = c.tx_power_dbm - pl                      # mean rx power
         noise_dbm = c.noise_dbm_per_hz + 10 * np.log10(c.bandwidth_hz)
         snr_lin = 10 ** ((p_rx_dbm - noise_dbm) / 10)       # (K,)
-        h2 = self.rng.exponential(size=(c.fading_samples, len(dist_km)))
-        rate = c.bandwidth_hz * np.mean(np.log2(1 + snr_lin[None, :] * h2),
-                                        axis=0)
+        with obs.span("repro.plan.channel") as sp:
+            if sp.on:
+                sp.stat(rows=1, periods=1)
+            h2 = self.rng.exponential(size=(c.fading_samples,
+                                            len(dist_km)))
+            rate = c.bandwidth_hz * np.mean(
+                np.log2(1 + snr_lin[None, :] * h2), axis=0)
         return rate                                          # bits/s
 
     def avg_rate_updown_rows(self, dist_km: np.ndarray, periods: int,
@@ -82,21 +88,25 @@ class Cell:
         intermediate math stays well-behaved; the solver's active mask
         zeroes its batchsize and bandwidth share, so the value never
         reaches a result).  Returns (rates_up (P, K'), rates_down (P, K'))
-        with K' = ``pad_to`` or K."""
+        with K' = ``pad_to`` or K.  One ``repro.plan.channel`` span."""
         c = self.cfg
-        pl = path_loss_db(dist_km)
-        p_rx_dbm = c.tx_power_dbm - pl
-        noise_dbm = c.noise_dbm_per_hz + 10 * np.log10(c.bandwidth_hz)
-        snr_lin = 10 ** ((p_rx_dbm - noise_dbm) / 10)        # (K,)
-        h2 = self.rng.exponential(
-            size=(periods, 2, c.fading_samples, len(dist_km)))
-        rate = c.bandwidth_hz * np.mean(
-            np.log2(1 + snr_lin[None, None, None, :] * h2), axis=2)
-        up, down = rate[:, 0], rate[:, 1]                    # bits/s
-        if pad_to is not None and pad_to > len(dist_km):
-            fill = np.full((periods, pad_to - len(dist_km)), c.bandwidth_hz)
-            up = np.concatenate([up, fill], axis=1)
-            down = np.concatenate([down, fill], axis=1)
+        with obs.span("repro.plan.channel") as sp:
+            if sp.on:
+                sp.stat(rows=1, periods=periods)
+            pl = path_loss_db(dist_km)
+            p_rx_dbm = c.tx_power_dbm - pl
+            noise_dbm = c.noise_dbm_per_hz + 10 * np.log10(c.bandwidth_hz)
+            snr_lin = 10 ** ((p_rx_dbm - noise_dbm) / 10)    # (K,)
+            h2 = self.rng.exponential(
+                size=(periods, 2, c.fading_samples, len(dist_km)))
+            rate = c.bandwidth_hz * np.mean(
+                np.log2(1 + snr_lin[None, None, None, :] * h2), axis=2)
+            up, down = rate[:, 0], rate[:, 1]                # bits/s
+            if pad_to is not None and pad_to > len(dist_km):
+                fill = np.full((periods, pad_to - len(dist_km)),
+                               c.bandwidth_hz)
+                up = np.concatenate([up, fill], axis=1)
+                down = np.concatenate([down, fill], axis=1)
         return up, down
 
     def sample_rates(self, k: int):

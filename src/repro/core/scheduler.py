@@ -14,6 +14,7 @@ from typing import List, Optional, Sequence
 
 import numpy as np
 
+from repro import obs
 from repro.channels.model import Cell, CellConfig
 from repro.core.baselines import POLICIES
 from repro.core.efficiency import XiEstimator, lr_scale
@@ -23,6 +24,16 @@ from repro.dynamics import (EnergyBudget, Fading, FadingProcess, Faults,
                             FaultProcess)
 from repro.dynamics.energy import batch_caps, energy_spend
 from repro.topology import ParticipationSampler, Sampling, Topology
+
+
+def _solve(fn, *args, **kw):
+    """One outermost Algorithm-1 / slot-allocation solve as a
+    ``repro.plan.solve`` span; its ``rows`` stat counts the rows solved
+    (the leading axis of the first rate or batch array)."""
+    with obs.span("repro.plan.solve") as sp:
+        if sp.on:
+            sp.stat(rows=len(args[1]))
+        return fn(*args, **kw)
 
 
 @dataclass(frozen=True)
@@ -403,15 +414,15 @@ class FeelScheduler:
                 1, self.b_max + 1, size=(periods, K)).astype(float)
         avail = self._compose_avail(part, keep, periods)
         if avail is None:
-            tau_up, tau_down, latency = fixed_slot_rows(
-                self.devices, batch, pup, pdown,
+            tau_up, tau_down, latency = _solve(
+                fixed_slot_rows, self.devices, batch, pup, pdown,
                 self.payload_bits, c.frame_up_s, c.frame_down_s)
             batch_f = batch
         else:
             fr = FleetRows.from_devices(self.devices,
                                         periods).with_mask(avail)
-            tau_up, tau_down, latency = fixed_slot_rows(
-                fr, batch * avail, pup, pdown,
+            tau_up, tau_down, latency = _solve(
+                fixed_slot_rows, fr, batch * avail, pup, pdown,
                 self.payload_bits, c.frame_up_s, c.frame_down_s)
             batch_f = batch * avail
         mask_now = avail
@@ -499,7 +510,8 @@ class FeelScheduler:
             b_prev = (np.full(int(reopt.sum()), self._b_cache)
                       if warm else None)
             cap = self.xi_est.decay_cap if closed_loop else None
-            b_star = optimize_batch_rows(
+            b_star = _solve(
+                optimize_batch_rows,
                 rows if solve_mask is None else rows.take(reopt),
                 pup[reopt], pdown[reopt],
                 self.payload_bits, c.frame_up_s, c.frame_down_s, xi,
@@ -516,9 +528,9 @@ class FeelScheduler:
                 B[p] = carry
         else:
             B[:] = carry
-        sol = solve_period_rows(rows, pup, pdown,
-                                self.payload_bits, c.frame_up_s,
-                                c.frame_down_s, xi, B, self.b_max)
+        sol = _solve(solve_period_rows, rows, pup, pdown,
+                     self.payload_bits, c.frame_up_s,
+                     c.frame_down_s, xi, B, self.b_max)
         self._b_cache = float(B[-1])
         self._period += periods
         batch_f = sol["batch"]
@@ -622,7 +634,8 @@ class FeelScheduler:
                 warm = warm_start and not np.isnan(carry).all()
                 b_prev = (np.repeat(carry, P)[rf] if warm else None)
                 cap = self.xi_est.decay_cap if closed_loop else None
-                b_star = optimize_batch_rows(
+                b_star = _solve(
+                    optimize_batch_rows,
                     fr.take(rf), flat_up[rf], flat_down[rf],
                     self.payload_bits, c.frame_up_s, c.frame_down_s, xi,
                     self.b_max, b_prev=b_prev,
@@ -640,10 +653,10 @@ class FeelScheduler:
                     carry[ci] = cur
             else:
                 B_cp[:] = np.where(np.isnan(carry), 1.0, carry)[:, None]
-            sol = solve_period_rows(fr, flat_up, flat_down,
-                                    self.payload_bits, c.frame_up_s,
-                                    c.frame_down_s, xi,
-                                    B_cp.reshape(C * P), self.b_max)
+            sol = _solve(solve_period_rows, fr, flat_up, flat_down,
+                         self.payload_bits, c.frame_up_s,
+                         c.frame_down_s, xi,
+                         B_cp.reshape(C * P), self.b_max)
             bt = np.where(fr.active,
                           np.maximum(np.round(np.nan_to_num(sol["batch"]))
                                      .astype(int), 1), 0)
@@ -659,9 +672,9 @@ class FeelScheduler:
                 pol = self.rng.integers(
                     1, self.b_max + 1, size=(P, K)).astype(float)
             batch_rows = np.broadcast_to(pol, (C, P, K)).reshape(C * P, K)
-            tau_u_r, tau_d_r, lat_r = fixed_slot_rows(
-                fr, batch_rows * solve_mask, flat_up, flat_down,
-                self.payload_bits, c.frame_up_s, c.frame_down_s)
+            tau_u_r, tau_d_r, lat_r = _solve(
+                fixed_slot_rows, fr, batch_rows * solve_mask, flat_up,
+                flat_down, self.payload_bits, c.frame_up_s, c.frame_down_s)
             bt = np.where(fr.active,
                           np.maximum(np.round(batch_rows).astype(int), 1),
                           0)
@@ -699,9 +712,12 @@ class FeelScheduler:
             kw["xi"] = self.xi_est.xi
             if self._b_cache is not None and self._period % self.reopt_every:
                 kw["B"] = self._b_cache
-        res = POLICIES[self.policy](
-            self.devices, rates_up, rates_down, self.payload_bits,
-            c.frame_up_s, c.frame_down_s, self.b_max, **kw)
+        with obs.span("repro.plan.solve") as sp:
+            if sp.on:
+                sp.stat(rows=1)
+            res = POLICIES[self.policy](
+                self.devices, rates_up, rates_down, self.payload_bits,
+                c.frame_up_s, c.frame_down_s, self.b_max, **kw)
         if self.policy == "proposed":
             self._b_cache = res.global_batch
         batch = np.maximum(np.round(res.batch).astype(int), 1)
@@ -824,7 +840,8 @@ def plan_horizons_batch(schedulers: Sequence[FeelScheduler],
                      else s.xi_est.decay_cap for s in scheds]), P)[rf]
                 if np.isfinite(caps).any():
                     dl_cap = caps
-            b_star = optimize_batch_rows(
+            b_star = _solve(
+                optimize_batch_rows,
                 flat_fleets.take(rf), flat_up[rf], flat_down[rf],
                 s0.payload_bits, c.frame_up_s, c.frame_down_s, xi_rows[rf],
                 s0.b_max, b_prev=b_prev, n_candidates=n_cand,
@@ -840,9 +857,9 @@ def plan_horizons_batch(schedulers: Sequence[FeelScheduler],
         else:
             for m, s in enumerate(scheds):
                 B[m, :] = s._b_cache
-        sol = solve_period_rows(flat_fleets, flat_up, flat_down,
-                                s0.payload_bits, c.frame_up_s, c.frame_down_s,
-                                xi_rows, B.reshape(M * P), s0.b_max)
+        sol = _solve(solve_period_rows, flat_fleets, flat_up, flat_down,
+                     s0.payload_bits, c.frame_up_s, c.frame_down_s,
+                     xi_rows, B.reshape(M * P), s0.b_max)
         # round active batches up to >= 1; padded columns and sampled-out
         # users stay exactly 0
         batch = np.where(flat_fleets.active.reshape(M, P, K),
@@ -945,12 +962,15 @@ class DevScheduler:
         c = self.cell.cfg
         part = (None if self._participation is None
                 else self._participation.draw(periods))
-        idx = np.empty((periods, K, self.batch), np.int64)
-        for p in range(periods):         # same rng order as the PR-1 loop
-            idx[p] = np.stack(
-                [self.rng.choice(part_k, size=self.batch,
-                                 replace=len(part_k) < self.batch)
-                 for part_k in self.parts])
+        with obs.span("repro.plan.schedule") as sp:
+            idx = np.empty((periods, K, self.batch), np.int64)
+            for p in range(periods):     # the per-period rng order
+                idx[p] = np.stack(
+                    [self.rng.choice(part_k, size=self.batch,
+                                     replace=len(part_k) < self.batch)
+                     for part_k in self.parts])
+            if sp.on:
+                sp.stat(rows=1)
         rates_up, rates_down = self.cell.avg_rate_updown_rows(
             self._dist_km, periods)
         # one local epoch per period: ⌈|D_k|/B⌉ minibatch steps

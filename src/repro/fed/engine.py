@@ -41,6 +41,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.compression.sbc import compress_dense
 from repro.fed import feel_model
 
@@ -132,10 +133,14 @@ def host_to_device(tree):
 
     Floats → float32, ints → int32, bools pass through.  This is the
     single documented host↔device boundary; planners stay float64 on the
-    host side and nothing 64-bit crosses it.
+    host side and nothing 64-bit crosses it.  Each host leaf copied here
+    is counted by an open ``obs.upload`` span.
     """
     def cast(a):
+        from_host = not isinstance(a, jax.Array)
         a = jnp.asarray(a)
+        if from_host:
+            obs.crossed(a)
         kind = np.dtype(a.dtype).kind
         target = _DEVICE_DTYPES.get(kind)
         if target is not None and a.dtype != target:
@@ -145,13 +150,15 @@ def host_to_device(tree):
 
 
 def assert_device_safe(tree, where: str = "jit boundary"):
-    """Raise if any leaf about to enter a jitted program is 64-bit."""
-    for leaf in jax.tree_util.tree_leaves(tree):
-        dtype = np.dtype(getattr(leaf, "dtype", np.asarray(leaf).dtype))
-        if dtype.itemsize == 8 and dtype.kind in "fiuc":
-            raise TypeError(
-                f"64-bit array ({dtype}) reached {where}; host planners "
-                "must cross through engine.host_to_device first")
+    """Raise if any leaf about to enter a jitted program is 64-bit (the
+    ``repro.dispatch.check`` span)."""
+    with obs.span("repro.dispatch.check"):
+        for leaf in jax.tree_util.tree_leaves(tree):
+            dtype = np.dtype(getattr(leaf, "dtype", np.asarray(leaf).dtype))
+            if dtype.itemsize == 8 and dtype.kind in "fiuc":
+                raise TypeError(
+                    f"64-bit array ({dtype}) reached {where}; host planners "
+                    "must cross through engine.host_to_device first")
     return tree
 
 
@@ -352,37 +359,46 @@ def pad_schedule(schedule: Schedule, k: int) -> Schedule:
 
 def _period_step(data_x, data_y, test_x, test_y, local_steps,
                  compress, ratio, carry, xs):
+    # named scopes (grad / loss / sbc / aggregate / eval) only add op_name
+    # metadata: a profile attributes each device op to its phase, and the
+    # values are unchanged
     params, residual = carry
     idx, w, bk, lr = xs["idx"], xs["weight"], xs["batch"], xs["lr"]
-    # active: (K,) f32 {0,1} — THIS period's user mask, a per-step scan
-    # input (time-varying per-round participation; the PR-4 static padded
-    # mask is the constant special case).  The schedule already carries
-    # zero weights/batch for inactive users; multiplying keeps that
-    # invariant even for hand-built schedules (x * 1.0 == x bitwise, so
-    # fully-active rows are unchanged).
-    active = xs["active"]
-    w = w * active[:, None]
-    bk = bk * active
-    x = data_x[idx]                              # (K, slot, D)
-    y = data_y[idx]
-    xf = x.reshape(-1, x.shape[-1])
-    yf = y.reshape(-1)
-    wf = w.reshape(-1)
-    loss_before = feel_model.loss_fn(params, xf, yf, wf)
+    with jax.named_scope("grad"):
+        # active: (K,) f32 {0,1} — THIS period's user mask, a per-step
+        # scan input (time-varying per-round participation; the static
+        # padded mask is the constant special case).  The schedule
+        # already carries zero weights/batch for inactive users;
+        # multiplying keeps that invariant even for hand-built schedules
+        # (x * 1.0 == x bitwise, so fully-active rows are unchanged).
+        active = xs["active"]
+        w = w * active[:, None]
+        bk = bk * active
+        x = data_x[idx]                          # (K, slot, D)
+        y = data_y[idx]
+        xf = x.reshape(-1, x.shape[-1])
+        yf = y.reshape(-1)
+        wf = w.reshape(-1)
+    with jax.named_scope("loss"):
+        loss_before = feel_model.loss_fn(params, xf, yf, wf)
 
-    if local_steps == 1:
-        grads = jax.vmap(jax.grad(feel_model.loss_fn),
-                         in_axes=(None, 0, 0, 0))(params, x, y, w)
-    else:
-        # tau>1: per-device local SGD; upload the cumulative update
-        # (parameter delta) as the "gradient" (paper §VII extension)
-        dev_params = tree_map(
-            lambda a: jnp.broadcast_to(a, (x.shape[0],) + a.shape), params)
-        for _ in range(local_steps):
-            g = jax.vmap(jax.grad(feel_model.loss_fn))(dev_params, x, y, w)
-            dev_params = tree_map(lambda p, gg: p - lr * gg, dev_params, g)
-        grads = tree_map(lambda p0, pk: (p0[None] - pk) / lr,
-                         params, dev_params)
+    with jax.named_scope("grad"):
+        if local_steps == 1:
+            grads = jax.vmap(jax.grad(feel_model.loss_fn),
+                             in_axes=(None, 0, 0, 0))(params, x, y, w)
+        else:
+            # tau>1: per-device local SGD; upload the cumulative update
+            # (parameter delta) as the "gradient" (paper §VII extension)
+            dev_params = tree_map(
+                lambda a: jnp.broadcast_to(a, (x.shape[0],) + a.shape),
+                params)
+            for _ in range(local_steps):
+                g = jax.vmap(jax.grad(feel_model.loss_fn))(dev_params, x,
+                                                           y, w)
+                dev_params = tree_map(lambda p, gg: p - lr * gg,
+                                      dev_params, g)
+            grads = tree_map(lambda p0, pk: (p0[None] - pk) / lr,
+                             params, dev_params)
 
     if compress:
         # per-device SBC: every device sparsifies its OWN upload (the
@@ -390,20 +406,24 @@ def _period_step(data_x, data_y, test_x, test_y, local_steps,
         # top-k fraction a function of the device payload alone — a padded
         # (all-zero-gradient) user row compresses to exact zeros and the
         # active rows compress identically at any fleet padding.
-        grads, residual = jax.vmap(
-            lambda g, r: compress_dense(g, ratio, r))(grads, residual)
+        with jax.named_scope("sbc"):
+            grads, residual = jax.vmap(
+                lambda g, r: compress_dense(g, ratio, r))(grads, residual)
     # eq. (1): weighted average by B_k (padded rows carry B_k = 0).  A
     # positive ``aggden`` fixes the denominator (Horvitz-Thompson
     # weighted sampling: p·Σ_all b̄_k); zero falls back to the realized
     # cohort sum, which is the classic (biased-under-sampling) estimator
     # and bitwise identical to the pre-aggden step.
-    den = xs["aggden"]
-    wk = bk / jnp.where(den > 0, den, jnp.sum(bk))
-    agg = tree_map(lambda g: jnp.tensordot(wk, g, axes=1), grads)
-    params = tree_map(lambda p, g: p - lr * g, params, agg)
+    with jax.named_scope("aggregate"):
+        den = xs["aggden"]
+        wk = bk / jnp.where(den > 0, den, jnp.sum(bk))
+        agg = tree_map(lambda g: jnp.tensordot(wk, g, axes=1), grads)
+        params = tree_map(lambda p, g: p - lr * g, params, agg)
 
-    loss_after = feel_model.loss_fn(params, xf, yf, wf)
-    acc = feel_model.accuracy(params, test_x, test_y)
+    with jax.named_scope("loss"):
+        loss_after = feel_model.loss_fn(params, xf, yf, wf)
+    with jax.named_scope("eval"):
+        acc = feel_model.accuracy(params, test_x, test_y)
     return (params, residual), (loss_after, acc, loss_before - loss_after)
 
 
@@ -423,14 +443,15 @@ def _trajectory_fn(local_steps: int, compress: bool, ratio: float,
     if batched:
         run = jax.vmap(run, in_axes=(0, 0, 0, 0, None, None, None, None))
 
-    def traced(params0, residual0, active, xs, *data):
+    def feel_trajectory(params0, residual0, active, xs, *data):
         # host side effect at trace time: ledger entry (exactly one/trace).
         # Must sit OUTSIDE the vmap so the signature keeps the batch axis
         # (inside, distinct-N programs would collide into one triple).
         _record_trace("feel", key, (params0, residual0, active, xs, *data))
         return run(params0, residual0, active, xs, *data)
 
-    return jax.jit(traced)
+    # the function's name labels the program in a profile
+    return jax.jit(feel_trajectory)
 
 
 def trajectory_program(local_steps: int = 1, compress: bool = True,
@@ -476,6 +497,18 @@ def run_trajectory(params0, residual0, schedule: Schedule, data, test, *,
     return fn(*assert_device_safe(args, "run_trajectory"))
 
 
+def enqueue(fn, *args):
+    """Call a jitted trajectory program inside the ``repro.dispatch.enqueue``
+    span; its ``jit_traces`` stat is the number of traces the call made
+    (a retrace shows where it happened)."""
+    with obs.span("repro.dispatch.enqueue") as sp:
+        before = trace_count() if sp.on else 0
+        out = fn(*args)
+        if sp.on:
+            sp.stat(jit_traces=trace_count() - before)
+    return out
+
+
 def stack_schedules(schedules: Sequence[Schedule]):
     """Stack per-scenario schedules along a leading batch axis → scan xs."""
     per_seed = [s.stacked_xs() for s in schedules]
@@ -490,10 +523,10 @@ def _normalize_active_batch(active, n: int, periods: int, k: int):
     per-period multiply reuses the same {0,1} row every step)."""
     if active is None:
         return jnp.ones((n, periods, k), jnp.float32)
-    active = jnp.asarray(active)
+    active = host_to_device(active)
     if active.ndim == 2:
         active = jnp.broadcast_to(active[:, None, :], (n, periods, k))
-    return host_to_device(active)
+    return active
 
 
 def run_trajectory_batch(params0, residual0, schedules: Sequence[Schedule],
@@ -516,18 +549,19 @@ def run_trajectory_batch(params0, residual0, schedules: Sequence[Schedule],
     pad upstream) and the datasets are replicated; ``mesh=None`` keeps the
     single-device layout.
     """
-    xs = stack_schedules(schedules)
-    active = _normalize_active_batch(active, len(schedules),
-                                     schedules[0].periods,
-                                     schedules[0].idx.shape[1])
-    data_args = host_to_device((data.x, data.y, test.x, test.y))
-    if mesh is not None:
-        (params0, residual0, active, xs), data_args = _shard_batch_args(
-            mesh, (params0, residual0, active, xs), data_args)
+    with obs.upload("repro.dispatch.upload"):
+        xs = stack_schedules(schedules)
+        active = _normalize_active_batch(active, len(schedules),
+                                         schedules[0].periods,
+                                         schedules[0].idx.shape[1])
+        data_args = host_to_device((data.x, data.y, test.x, test.y))
+        if mesh is not None:
+            (params0, residual0, active, xs), data_args = _shard_batch_args(
+                mesh, (params0, residual0, active, xs), data_args)
     fn = _trajectory_fn(local_steps, compress, float(ratio), True)
     assert_device_safe((params0, residual0, active, xs, data_args),
                        "run_trajectory_batch")
-    return fn(params0, residual0, active, xs, *data_args)
+    return enqueue(fn, params0, residual0, active, xs, *data_args)
 
 
 # ---------------------------------------------------------------------------
@@ -543,28 +577,32 @@ def _dev_step(data_x, data_y, test_x, test_y, lr, average,
     # again; for the always-active case g * 1.0 == g keeps the trained
     # rows bitwise unchanged.
     idx, active = xs
-    x = data_x[idx]
-    y = data_y[idx]
-    g = jax.vmap(jax.grad(feel_model.loss_fn))(dev_params, x, y)
-    dev_params = tree_map(
-        lambda p, gg: p - lr * (gg * active.reshape(
-            (-1,) + (1,) * (gg.ndim - 1))), dev_params, g)
+    with jax.named_scope("grad"):
+        x = data_x[idx]
+        y = data_y[idx]
+        g = jax.vmap(jax.grad(feel_model.loss_fn))(dev_params, x, y)
+        dev_params = tree_map(
+            lambda p, gg: p - lr * (gg * active.reshape(
+                (-1,) + (1,) * (gg.ndim - 1))), dev_params, g)
     # masked device mean: padded / sampled-out user rows (active 0) must
     # never enter a parameter average — denominator is the active count
     # (for an all-active mask this is sum(a)/K == mean bitwise)
-    n_active = jnp.sum(active)
+    with jax.named_scope("aggregate"):
+        n_active = jnp.sum(active)
 
-    def masked_mean(a):
-        m = active.reshape((-1,) + (1,) * (a.ndim - 1))
-        return jnp.sum(a * m, axis=0) / n_active
+        def masked_mean(a):
+            m = active.reshape((-1,) + (1,) * (a.ndim - 1))
+            return jnp.sum(a * m, axis=0) / n_active
 
-    if average:
-        # FedAvg: replace every device copy with the parameter mean
-        dev_params = tree_map(
-            lambda a: jnp.broadcast_to(masked_mean(a), a.shape), dev_params)
-    avg = tree_map(masked_mean, dev_params)
-    loss = feel_model.loss_fn(avg, test_x, test_y)
-    acc = feel_model.accuracy(avg, test_x, test_y)
+        if average:
+            # FedAvg: replace every device copy with the parameter mean
+            dev_params = tree_map(
+                lambda a: jnp.broadcast_to(masked_mean(a), a.shape),
+                dev_params)
+        avg = tree_map(masked_mean, dev_params)
+    with jax.named_scope("eval"):
+        loss = feel_model.loss_fn(avg, test_x, test_y)
+        acc = feel_model.accuracy(avg, test_x, test_y)
     return dev_params, (loss, acc)
 
 
@@ -581,12 +619,12 @@ def _dev_trajectory_fn(average: bool, batched: bool = False):
     if batched:
         run = jax.vmap(run, in_axes=(0, 0, 0, 0, None, None, None, None))
 
-    def traced(dev_params0, idx, lr, active, *data):
+    def dev_trajectory(dev_params0, idx, lr, active, *data):
         # trace-time ledger entry — outside the vmap, see _trajectory_fn
         _record_trace("dev", key, (dev_params0, idx, lr, active, *data))
         return run(dev_params0, idx, lr, active, *data)
 
-    return jax.jit(traced)
+    return jax.jit(dev_trajectory)
 
 
 def run_dev_trajectory(dev_params0, idx: np.ndarray, lr: float, data, test,
@@ -645,16 +683,18 @@ def run_dev_trajectory_batch(dev_params0, idx: np.ndarray, lr: np.ndarray,
     every parameter average).  ``mesh`` shards N across devices as in
     :func:`run_trajectory_batch`.
     """
-    idx = host_to_device(np.asarray(idx))
-    active = _normalize_active_batch(active, idx.shape[0], idx.shape[1],
-                                     idx.shape[2])
-    batched = (dev_params0, idx, *host_to_device((np.asarray(lr), active)))
-    data_args = host_to_device((data.x, data.y, test.x, test.y))
-    if mesh is not None:
-        batched, data_args = _shard_batch_args(mesh, batched, data_args)
+    with obs.upload("repro.dispatch.upload"):
+        idx = host_to_device(np.asarray(idx))
+        active = _normalize_active_batch(active, idx.shape[0], idx.shape[1],
+                                         idx.shape[2])
+        batched = (dev_params0, idx,
+                   *host_to_device((np.asarray(lr), active)))
+        data_args = host_to_device((data.x, data.y, test.x, test.y))
+        if mesh is not None:
+            batched, data_args = _shard_batch_args(mesh, batched, data_args)
     fn = _dev_trajectory_fn(bool(average), batched=True)
     assert_device_safe((batched, data_args), "run_dev_trajectory_batch")
-    return fn(*batched, *data_args)
+    return enqueue(fn, *batched, *data_args)
 
 
 def resume_dev_trajectory_batch(state: EngineState, idx: np.ndarray,
@@ -695,54 +735,68 @@ def _hier_period_step(data_x, data_y, test_x, test_y, member, local_steps,
     params_e, residual = carry                    # leaves (E, ...) / (K, ...)
     idx, w, bk, lr = xs["idx"], xs["weight"], xs["batch"], xs["lr"]
     active, cloud = xs["active"], xs["cloud"]
-    w = w * active[:, None]
-    bk = bk * active
-    # edge bookkeeping: s_e — per-edge batch mass; wk — per-edge eq. (1)
-    # weights (a participant-free edge gets all-zero weights and a guard
-    # denominator, so its replica simply holds still this period); beta —
-    # batch share per edge, the cloud-merge and evaluation weights
-    s_e = jnp.tensordot(member, bk, axes=1)                       # (E,)
-    wk = member * bk[None, :] / jnp.where(s_e > 0, s_e, 1.0)[:, None]
-    beta = s_e / jnp.sum(s_e)                                     # (E,)
+    with jax.named_scope("aggregate"):
+        w = w * active[:, None]
+        bk = bk * active
+        # edge bookkeeping: s_e — per-edge batch mass; wk — per-edge eq.
+        # (1) weights (a participant-free edge gets all-zero weights and a
+        # guard denominator, so its replica simply holds still this
+        # period); beta — batch share per edge, the cloud-merge and
+        # evaluation weights
+        s_e = jnp.tensordot(member, bk, axes=1)                   # (E,)
+        wk = member * bk[None, :] / jnp.where(s_e > 0, s_e, 1.0)[:, None]
+        beta = s_e / jnp.sum(s_e)                                 # (E,)
 
     def cloud_view(tree):
         return tree_map(lambda a: jnp.tensordot(beta, a, axes=1), tree)
 
-    # each user trains from ITS edge's replica (one-hot gather)
-    user_params = tree_map(
-        lambda a: jnp.tensordot(member, a, axes=((0,), (0,))), params_e)
-    x = data_x[idx]                                # (K, slot, D)
-    y = data_y[idx]
-    xf = x.reshape(-1, x.shape[-1])
-    yf = y.reshape(-1)
-    wf = w.reshape(-1)
-    global_before = cloud_view(params_e)
-    loss_before = feel_model.loss_fn(global_before, xf, yf, wf)
+    with jax.named_scope("grad"):
+        # each user trains from ITS edge's replica (one-hot gather)
+        user_params = tree_map(
+            lambda a: jnp.tensordot(member, a, axes=((0,), (0,))), params_e)
+        x = data_x[idx]                            # (K, slot, D)
+        y = data_y[idx]
+        xf = x.reshape(-1, x.shape[-1])
+        yf = y.reshape(-1)
+        wf = w.reshape(-1)
+    with jax.named_scope("loss"):
+        global_before = cloud_view(params_e)
+        loss_before = feel_model.loss_fn(global_before, xf, yf, wf)
 
-    if local_steps == 1:
-        grads = jax.vmap(jax.grad(feel_model.loss_fn))(user_params, x, y, w)
-    else:
-        dev_params = user_params
-        for _ in range(local_steps):
-            g = jax.vmap(jax.grad(feel_model.loss_fn))(dev_params, x, y, w)
-            dev_params = tree_map(lambda p, gg: p - lr * gg, dev_params, g)
-        grads = tree_map(lambda p0, pk: (p0 - pk) / lr,
-                         user_params, dev_params)
+    with jax.named_scope("grad"):
+        if local_steps == 1:
+            grads = jax.vmap(jax.grad(feel_model.loss_fn))(user_params, x,
+                                                           y, w)
+        else:
+            dev_params = user_params
+            for _ in range(local_steps):
+                g = jax.vmap(jax.grad(feel_model.loss_fn))(dev_params, x,
+                                                           y, w)
+                dev_params = tree_map(lambda p, gg: p - lr * gg,
+                                      dev_params, g)
+            grads = tree_map(lambda p0, pk: (p0 - pk) / lr,
+                             user_params, dev_params)
 
     if compress:
-        grads, residual = jax.vmap(
-            lambda g, r: compress_dense(g, ratio, r))(grads, residual)
-    # per-edge eq. (1) aggregation and SGD step on each replica
-    agg = tree_map(lambda g: jnp.tensordot(wk, g, axes=1), grads)  # (E, ...)
-    params_e = tree_map(lambda p, g: p - lr * g, params_e, agg)
-    # cloud round: replicas -> batch-weighted global average, broadcast back
-    params_e = tree_map(
-        lambda a: jnp.where(cloud > 0.5,
-                            jnp.broadcast_to(jnp.tensordot(beta, a, axes=1),
-                                             a.shape), a), params_e)
-    global_after = cloud_view(params_e)
-    loss_after = feel_model.loss_fn(global_after, xf, yf, wf)
-    acc = feel_model.accuracy(global_after, test_x, test_y)
+        with jax.named_scope("sbc"):
+            grads, residual = jax.vmap(
+                lambda g, r: compress_dense(g, ratio, r))(grads, residual)
+    with jax.named_scope("aggregate"):
+        # per-edge eq. (1) aggregation and SGD step on each replica
+        agg = tree_map(lambda g: jnp.tensordot(wk, g, axes=1), grads)
+        params_e = tree_map(lambda p, g: p - lr * g, params_e, agg)
+        # cloud round: replicas -> batch-weighted global average,
+        # broadcast back
+        params_e = tree_map(
+            lambda a: jnp.where(cloud > 0.5,
+                                jnp.broadcast_to(
+                                    jnp.tensordot(beta, a, axes=1),
+                                    a.shape), a), params_e)
+        global_after = cloud_view(params_e)
+    with jax.named_scope("loss"):
+        loss_after = feel_model.loss_fn(global_after, xf, yf, wf)
+    with jax.named_scope("eval"):
+        acc = feel_model.accuracy(global_after, test_x, test_y)
     return (params_e, residual), (loss_after, acc, loss_before - loss_after)
 
 
@@ -766,14 +820,15 @@ def _hier_trajectory_fn(local_steps: int, compress: bool, ratio: float,
         run = jax.vmap(run, in_axes=(0, 0, 0, 0, 0, 0,
                                      None, None, None, None))
 
-    def traced(params_e0, residual0, member, active, cloud, xs, *data):
+    def hier_trajectory(params_e0, residual0, member, active, cloud, xs,
+                        *data):
         # trace-time ledger entry — outside the vmap, see _trajectory_fn
         _record_trace("hier", key,
                       (params_e0, residual0, member, active, cloud, xs,
                        *data))
         return run(params_e0, residual0, member, active, cloud, xs, *data)
 
-    return jax.jit(traced)
+    return jax.jit(hier_trajectory)
 
 
 def hier_trajectory_program(local_steps: int = 1, compress: bool = True,
@@ -797,23 +852,25 @@ def run_hier_trajectory_batch(params0, residual0, member, cloud,
     flags (``Topology.cloud_rounds``); ``active`` as in
     :func:`run_trajectory_batch`.
     """
-    xs = stack_schedules(schedules)
-    active = _normalize_active_batch(active, len(schedules),
-                                     schedules[0].periods,
-                                     schedules[0].idx.shape[1])
-    member = host_to_device(np.asarray(member))
-    cloud = host_to_device(np.asarray(cloud))
-    data_args = host_to_device((data.x, data.y, test.x, test.y))
-    if mesh is not None:
-        (params0, residual0, member, active, cloud, xs), data_args = \
-            _shard_batch_args(
-                mesh, (params0, residual0, member, active, cloud, xs),
-                data_args)
+    with obs.upload("repro.dispatch.upload"):
+        xs = stack_schedules(schedules)
+        active = _normalize_active_batch(active, len(schedules),
+                                         schedules[0].periods,
+                                         schedules[0].idx.shape[1])
+        member = host_to_device(np.asarray(member))
+        cloud = host_to_device(np.asarray(cloud))
+        data_args = host_to_device((data.x, data.y, test.x, test.y))
+        if mesh is not None:
+            (params0, residual0, member, active, cloud, xs), data_args = \
+                _shard_batch_args(
+                    mesh, (params0, residual0, member, active, cloud, xs),
+                    data_args)
     fn = _hier_trajectory_fn(local_steps, compress, float(ratio),
                              int(member.shape[1]), True)
     assert_device_safe((params0, residual0, member, active, cloud, xs,
                         data_args), "run_hier_trajectory_batch")
-    return fn(params0, residual0, member, active, cloud, xs, *data_args)
+    return enqueue(fn, params0, residual0, member, active, cloud, xs,
+                   *data_args)
 
 
 def resume_hier_trajectory_batch(state: EngineState, member, cloud,

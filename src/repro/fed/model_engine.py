@@ -33,11 +33,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.compression.sbc import sbc_uplink
 from repro.configs.base import ArchConfig, SSMConfig
 from repro.fed.engine import (EngineState, _normalize_active_batch,
                               _record_trace, _shard_batch_args,
-                              assert_device_safe, host_to_device,
+                              assert_device_safe, enqueue, host_to_device,
                               stack_schedules)
 from repro.fed.train_step import TrainState, make_loss_fn
 from repro.models.model import Runtime, forward
@@ -120,47 +121,55 @@ def tokenize(data, seq_cap: int = SEQ_CAP, vocab: int = VOCAB):
 
 def _model_period_step(cfg, rt, loss_fn, opt, compress, ratio,
                        tok, lab, test_tok, test_y, carry, xs):
+    # named scopes as in engine._period_step (op_name metadata only)
     state, residual = carry
     idx, w, bk, lr = xs["idx"], xs["weight"], xs["batch"], xs["lr"]
-    # same active-mask invariant as engine._period_step: the schedule
-    # already zeroes inactive users; multiplying keeps it for hand-built
-    # schedules and is bitwise free for fully-active rows
-    active = xs["active"]
-    w = w * active[:, None]
-    bk = bk * active
-    t = tok[idx]                                  # (K, slot, S)
-    l_ = lab[idx]
-    wt = jnp.broadcast_to(w[..., None], l_.shape).astype(jnp.float32)
-    flat = {"tokens": t.reshape(-1, t.shape[-1]),
-            "labels": l_.reshape(-1, l_.shape[-1]),
-            "weights": wt.reshape(-1, wt.shape[-1])}
-    loss_before = loss_fn(state.params, flat)[1]
+    with jax.named_scope("grad"):
+        # same active-mask invariant as engine._period_step: the schedule
+        # already zeroes inactive users; multiplying keeps it for
+        # hand-built schedules and is bitwise free for fully-active rows
+        active = xs["active"]
+        w = w * active[:, None]
+        bk = bk * active
+        t = tok[idx]                              # (K, slot, S)
+        l_ = lab[idx]
+        wt = jnp.broadcast_to(w[..., None], l_.shape).astype(jnp.float32)
+        flat = {"tokens": t.reshape(-1, t.shape[-1]),
+                "labels": l_.reshape(-1, l_.shape[-1]),
+                "weights": wt.reshape(-1, wt.shape[-1])}
+    with jax.named_scope("loss"):
+        loss_before = loss_fn(state.params, flat)[1]
 
     # Step 1-2: per-device gradients of the weighted-CE train-step loss on
     # each device's own slot batch (surplus slots carry zero weight)
     def dev_grad_loss(p, tk, lk, wk):
         return loss_fn(p, {"tokens": tk, "labels": lk, "weights": wk})[0]
 
-    grads = jax.vmap(jax.grad(dev_grad_loss),
-                     in_axes=(None, 0, 0, 0))(state.params, t, l_, wt)
+    with jax.named_scope("grad"):
+        grads = jax.vmap(jax.grad(dev_grad_loss),
+                         in_axes=(None, 0, 0, 0))(state.params, t, l_, wt)
     if compress:
         # per-device SBC with per-device error feedback — the kernel path
         # on accelerators, bitwise compress_dense on CPU (sbc_uplink)
-        grads, residual = jax.vmap(
-            lambda g, r: sbc_uplink(g, ratio, r))(grads, residual)
+        with jax.named_scope("sbc"):
+            grads, residual = jax.vmap(
+                lambda g, r: sbc_uplink(g, ratio, r))(grads, residual)
     # eq. (1): weighted average by B_k (padded rows carry B_k = 0); a
     # positive aggden fixes the denominator as in the MLP engine
-    den = xs["aggden"]
-    wk = bk / jnp.where(den > 0, den, jnp.sum(bk))
-    agg = tree_map(lambda g: jnp.tensordot(wk, g, axes=1), grads)
-    updates, new_opt = opt.update(agg, state.opt, state.params, lr)
-    params = apply_updates(state.params, updates)
-    state = TrainState(params, new_opt, state.step + 1)
+    with jax.named_scope("aggregate"):
+        den = xs["aggden"]
+        wk = bk / jnp.where(den > 0, den, jnp.sum(bk))
+        agg = tree_map(lambda g: jnp.tensordot(wk, g, axes=1), grads)
+        updates, new_opt = opt.update(agg, state.opt, state.params, lr)
+        params = apply_updates(state.params, updates)
+        state = TrainState(params, new_opt, state.step + 1)
 
-    loss_after = loss_fn(params, flat)[1]
-    logits, _ = forward(cfg, params, test_tok, rt=rt)
-    acc = jnp.mean((jnp.argmax(logits[:, -1, :N_CLASSES], axis=-1)
-                    == test_y).astype(jnp.float32))
+    with jax.named_scope("loss"):
+        loss_after = loss_fn(params, flat)[1]
+    with jax.named_scope("eval"):
+        logits, _ = forward(cfg, params, test_tok, rt=rt)
+        acc = jnp.mean((jnp.argmax(logits[:, -1, :N_CLASSES], axis=-1)
+                        == test_y).astype(jnp.float32))
     return (state, residual), (loss_after, acc, loss_before - loss_after)
 
 
@@ -185,12 +194,12 @@ def _model_trajectory_fn(model_family: str, hidden: int, depth: int,
     if batched:
         run = jax.vmap(run, in_axes=(0, 0, 0, 0, None, None, None, None))
 
-    def traced(params0, residual0, active, xs, *data):
+    def model_trajectory(params0, residual0, active, xs, *data):
         # ledger entry OUTSIDE the vmap (same rationale as engine)
         _record_trace("model", key, (params0, residual0, active, xs, *data))
         return run(params0, residual0, active, xs, *data)
 
-    return jax.jit(traced)
+    return jax.jit(model_trajectory)
 
 
 def model_trajectory_program(model_family: str, hidden: int, depth: int,
@@ -229,21 +238,22 @@ def run_model_trajectory_batch(params0, residual0,
     ride the ``active`` mask — except the datasets enter as quantized
     token/label arrays (:func:`tokenize`).
     """
-    xs = stack_schedules(schedules)
-    active = _normalize_active_batch(active, len(schedules),
-                                     schedules[0].periods,
-                                     schedules[0].idx.shape[1])
-    tok, lab = tokenize(data)
-    test_tok, _ = tokenize(test)
-    data_args = host_to_device((tok, lab, test_tok, np.asarray(test.y)))
-    if mesh is not None:
-        (params0, residual0, active, xs), data_args = _shard_batch_args(
-            mesh, (params0, residual0, active, xs), data_args)
+    with obs.upload("repro.dispatch.upload"):
+        xs = stack_schedules(schedules)
+        active = _normalize_active_batch(active, len(schedules),
+                                         schedules[0].periods,
+                                         schedules[0].idx.shape[1])
+        tok, lab = tokenize(data)
+        test_tok, _ = tokenize(test)
+        data_args = host_to_device((tok, lab, test_tok, np.asarray(test.y)))
+        if mesh is not None:
+            (params0, residual0, active, xs), data_args = _shard_batch_args(
+                mesh, (params0, residual0, active, xs), data_args)
     fn = _model_trajectory_fn(model_family, int(hidden), int(depth),
                               bool(compress), float(ratio), True)
     assert_device_safe((params0, residual0, active, xs, data_args),
                        "run_model_trajectory_batch")
-    return fn(params0, residual0, active, xs, *data_args)
+    return enqueue(fn, params0, residual0, active, xs, *data_args)
 
 
 def resume_model_trajectory_batch(state: EngineState,

@@ -102,4 +102,5 @@ def flash_attention_bhsd(q, k, v, *, causal: bool = True,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_attention",
     )(q, k, v)

@@ -99,4 +99,5 @@ def flash_decode_bhd(q, k, v, pos, *, window: Optional[int] = None,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_decode",
     )(jnp.asarray(pos, jnp.int32)[None], q, k, v)

@@ -70,6 +70,7 @@ def sbc_stats(x2d, thr_row, *, block_rows: int = 512,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
+        name="sbc_stats",
     )(x2d, thr_row)
 
 
@@ -100,4 +101,5 @@ def sbc_apply(x2d, scalar_rows, *, block_rows: int = 512,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
+        name="sbc_apply",
     )(x2d, scalar_rows)

@@ -110,5 +110,6 @@ def ssd_scan(x, dt, A, Bm, Cm, *, chunk: int = 256, interpret: bool = False):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="ssd_scan",
     )(xdt, cs_col, cs_row, Bh, Ch)
     return y.transpose(0, 2, 3, 1, 4).reshape(B, S, H, P)

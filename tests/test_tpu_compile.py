@@ -47,6 +47,13 @@ def _n_kernels(text):
     return text.count('custom_call_target="tpu_custom_call"')
 
 
+def _named(text, name):
+    """Whether some kernel call of the compiled text carries ``name`` (the
+    ``pallas_call``'s ``name=``, which a profile shows for the call)."""
+    return any(name in line for line in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line)
+
+
 def _attention_shapes():
     """hidden 256: 4 query heads over 2 kv heads of 64 → BH = 512."""
     cfg = family_arch("transformer", 256, 3)
@@ -73,6 +80,7 @@ def _flash(q, k, v):
 def test_flash_attention_forward_compiles(one_chip):
     text = _compiled_text(_flash, _attention_shapes(), one_chip)
     assert _n_kernels(text) >= 1
+    assert _named(text, "flash_attention")
 
 
 def test_flash_attention_grad_compiles(one_chip):
@@ -85,7 +93,9 @@ def test_flash_attention_grad_compiles(one_chip):
 def test_ssd_forward_compiles(one_chip):
     shapes, chunk = _ssd_shapes()
     fn = lambda *a: ops.ssd(*a, chunk=chunk, interpret=False)  # noqa: E731
-    assert _n_kernels(_compiled_text(fn, shapes, one_chip)) >= 1
+    text = _compiled_text(fn, shapes, one_chip)
+    assert _n_kernels(text) >= 1
+    assert _named(text, "ssd_scan")
 
 
 def test_ssd_grad_compiles(one_chip):
@@ -107,3 +117,15 @@ def test_sbc_kernels_compile_under_client_vmap(one_chip, lead):
         fn = jax.vmap(fn)
     text = _compiled_text(fn, [lead + (256, 512), lead], one_chip)
     assert _n_kernels(text) == 2
+    assert _named(text, "sbc_stats") and _named(text, "sbc_apply")
+
+
+def test_flash_decode_compiles_with_its_name(one_chip):
+    """One query token against a 1,024-token cache, 4 query heads over 2
+    kv heads of 64."""
+    fn = lambda q, k, v: ops.flash_decode(  # noqa: E731
+        q, k, v, 1000, interpret=False)
+    text = _compiled_text(fn, [(2, 1, 4, 64), (2, 1024, 2, 64),
+                               (2, 1024, 2, 64)], one_chip)
+    assert _n_kernels(text) == 1
+    assert _named(text, "flash_decode")
