@@ -75,6 +75,7 @@ import numpy as np
 from repro import obs
 from repro.api.spec import ScenarioSpec
 from repro.channels.model import Cell
+from repro.compression import sbc
 from repro.core.scheduler import (DevScheduler, FeelScheduler,
                                   plan_horizons_batch)
 from repro.data.pipeline import (FederatedBatcher, partition_iid,
@@ -747,10 +748,21 @@ def dispatch_bucket(plan: BucketPlan, data, test, mesh=None,
     ``state`` resumes from a previous chunk's engine carry (chunked
     horizons); ``None`` initializes a fresh trajectory.  Runs as one
     ``repro.dispatch`` span (children ``init``, ``upload``, ``enqueue``)
-    carrying the plan's ids."""
+    carrying the plan's ids; where the period step runs
+    ``compress_dense`` (the MLP family with SBC on) its stat
+    ``sbc_count_passes`` counts the threshold search's passes over the
+    gradients per client-period, summed over the model's leaves."""
     dispatcher = (_dispatch_feel if plan.bucket.kind == "feel"
                   else _dispatch_dev)
-    with obs.span("repro.dispatch", **plan.ids):
+    with obs.span("repro.dispatch", **plan.ids) as sp:
+        spec0 = plan.bucket.rows[0].spec
+        if (sp.on and plan.bucket.kind == "feel" and spec0.compress
+                and spec0.model_family == "feel_mlp"):
+            leaves = jax.tree_util.tree_leaves(jax.eval_shape(
+                lambda: feel_model.init(jax.random.key(0), spec0.hidden,
+                                        depth=spec0.depth,
+                                        input_dim=plan.input_dim)))
+            sp.stat(sbc_count_passes=len(leaves) * sbc.count_passes())
         return dispatcher(plan, data, test, mesh, state=state)
 
 

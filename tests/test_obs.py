@@ -21,6 +21,7 @@ from repro.api import (AsyncExecutor, Experiment, ScenarioSpec,
                        SerialExecutor)
 from repro.api import lowering
 from repro.api.lowering import group_rows
+from repro.compression import sbc
 from repro.core import DeviceProfile
 from repro.data.pipeline import ClassificationData
 from repro.fed import engine
@@ -155,6 +156,14 @@ def test_executors_emit_the_span_tree(tmp_path, dataset, fleet, which):
         setup = [e for e in _named(evs, "repro.plan.setup")
                  if e.stats["bucket"] == serial]
         assert len(setup) == 1
+        # the threshold search's passes: 20 bisection steps a leaf, one w
+        # and one b per layer, where the period step runs SBC
+        passes = {e.stats.get("sbc_count_passes")
+                  for e in _named(evs, "repro.dispatch")
+                  if e.stats["bucket"] == serial}
+        want = (2 * bucket.rows[0].spec.depth * sbc.count_passes()
+                if bucket.kind == "feel" else None)
+        assert passes == {want}
 
     # a sampled bucket computes lanes it does not use
     assert any(e.stats["lanes_used"] < e.stats["lanes"] for e in plans)

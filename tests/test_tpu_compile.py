@@ -9,18 +9,23 @@ onto the kernel path although the backend is the CPU.  The topology is
 described inside a fixture, never at import, because only one process at
 a time may load the TPU library.
 """
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.compression import sbc
+from repro.fed import feel_model
 from repro.fed.model_engine import SEQ_CAP, family_arch
 from repro.kernels import ops
 from repro.models.mamba2 import dims as mamba2_dims
 
 K_CLIENTS = 6      # the table-2 fleet
+FIG45_LANES = (64, 6)  # the fig45 grid: 64 rows of 6 clients
 SLOT = 128         # b_max: sequences per client batch
 
 
@@ -129,3 +134,56 @@ def test_flash_decode_compiles_with_its_name(one_chip):
                                (2, 1024, 2, 64)], one_chip)
     assert _n_kernels(text) == 1
     assert _named(text, "flash_decode")
+
+
+def _computations(text):
+    """{name: lines} of every computation in compiled HLO text."""
+    out, lines = {}, None
+    for line in text.splitlines():
+        if line.endswith("{") and not line[0].isspace():
+            lines = out.setdefault(line.removeprefix("ENTRY ").split()[0], [])
+        elif line == "}":
+            lines = None
+        elif lines is not None:
+            lines.append(line)
+    return out
+
+
+def test_sbc_threshold_search_reads_each_leaf_once_a_pass(one_chip):
+    """``compress_dense`` over the fig45 lanes (64 rows of 6 clients, as
+    the engine nests them) at the MLP's leaf shapes (3072-256-256-10):
+    one loop per leaf of ``count_passes()`` passes, and in each pass one
+    fusion that reads the leaf's magnitudes and counts against all
+    ``2**_LEVELS - 1`` thresholds as sibling reductions."""
+    params = jax.eval_shape(lambda: feel_model.init(
+        jax.random.key(0), 256, depth=3, input_dim=3072))
+    lanes = jax.tree_util.tree_map(
+        lambda p: jax.ShapeDtypeStruct(FIG45_LANES + p.shape, p.dtype,
+                                       sharding=one_chip), params)
+    step = jax.vmap(jax.vmap(lambda g, r: sbc.compress_dense(g, 0.005, r)))
+    text = jax.jit(step).lower(lanes, lanes).compile().as_text()
+    comps = _computations(text)
+    loops = [line for line in text.splitlines() if " while(" in line]
+    # each loop carries its leaf's magnitudes, in a (rows, 128) slab where
+    # that fills whole (8, 128) tiles: the six clients never meet the tile
+    lead = ",".join(map(str, FIG45_LANES))
+    carried = [re.search(rf"f32\[{lead},([\d,]+)\]", loop)
+               for loop in loops]
+    dims = [[int(d) for d in m[1].split(",")] for m in carried]
+    assert sorted(math.prod(d) for d in dims) == sorted(
+        p.size for p in jax.tree_util.tree_leaves(params))
+    assert [len(d) for d in dims] == [
+        2 if math.prod(d) % (8 * 128) == 0 else 1 for d in dims]
+    assert all(d[-1] == 128 for d in dims if len(d) == 2)
+    for loop, leaf in zip(loops, (m[0] for m in carried)):
+        magnitudes = re.compile(re.escape(leaf) + r"\S* parameter\(")
+        cond = re.search(r"condition=(%[\w.\-]+)", loop)[1]
+        body = re.search(r"body=(%[\w.\-]+)", loop)[1]
+        assert any(f"constant({sbc.count_passes()})" in line
+                   for line in comps[cond])
+        reads = [line for line in comps[body] if " fusion(" in line
+                 and any(magnitudes.search(p) for p in comps[
+                     re.search(r"calls=(%[\w.\-]+)", line)[1]])]
+        assert len(reads) == 1, reads
+        outputs = reads[0].split(" fusion(")[0]
+        assert outputs.count(f"s32[{lead}]") == 2 ** sbc._LEVELS - 1
