@@ -94,7 +94,7 @@ class Judge:
         ``program=False`` the reference computed in ``dtype`` is compared
         with the float32 reference instead (the control)."""
         cfg, tr = self.cell.config, self.cell.traffic
-        model = reference.model_module(cfg["model_family"])
+        model = reference.config_module(cfg)
         train_in = model.train_inputs(cfg, train)
         test_in = model.test_inputs(cfg, test)
         run_ref = reference.make_trajectory(

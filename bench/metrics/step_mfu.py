@@ -6,7 +6,7 @@ are not counted — over the device time of the traced window (the union
 of the device's operation intervals, ``busy_s``, summed over the chips)
 at the chips' bf16 peak.  Host time, in which the device runs nothing,
 is left out: ``device_idle_share`` reads it.  Operations per example come
-from the configuration's reference model (``models/<family>.py``)."""
+from the configuration's reference model (``reference.config_module``)."""
 import sys
 from pathlib import Path
 
@@ -19,8 +19,7 @@ def read(ctx):
     if red.busy_s <= 0:
         return None
     cfg = ctx["config"]
-    per_ex = reference.model_module(
-        cfg["model_family"]).train_flops_per_example(cfg)
+    per_ex = reference.config_module(cfg).train_flops_per_example(cfg)
     flops = ctx["per_call"]["examples"] * ctx["n_calls"] * per_ex
     device_s = red.busy_s * len(red.devices)
     return 100.0 * flops / (device_s * ctx["peak"]["bf16_flops_per_s"])

@@ -42,19 +42,33 @@ def logits(params, x, prec):
     return x
 
 
-def loss(params, batch, w, cfg, prec):
-    """Σ w·nll / max(Σ w, 1e-9) — eq. (1)'s example weighting."""
+def nll_sums(params, batch, w, cfg, prec):
+    """(Σ w·nll, Σ w) of a batch: the two sums that blocks of a batch add
+    up to, before ``weighted_mean`` divides them once."""
     x, y = batch
     z = logits(params, x, prec)
     nll = jax.nn.logsumexp(z, axis=-1) - jnp.take_along_axis(
         z, y[:, None], axis=1)[:, 0]
-    return jnp.sum(nll * w) / jnp.maximum(jnp.sum(w), 1e-9)
+    return jnp.sum(nll * w), jnp.sum(w)
 
 
-def accuracy(params, batch, cfg, prec):
+def weighted_mean(s, n):
+    """Σ w·nll / max(Σ w, 1e-9) — eq. (1)'s example weighting."""
+    return s / jnp.maximum(n, 1e-9)
+
+
+def hits(params, batch, cfg, prec):
+    """(correct top-1 predictions, predictions made) of a batch."""
     x, y = batch
-    return jnp.mean((jnp.argmax(logits(params, x, prec), -1) == y)
-                    .astype(jnp.float32))
+    right = (jnp.argmax(logits(params, x, prec), -1) == y).astype(
+        jnp.float32)
+    return jnp.sum(right), right.size
+
+
+def logits_per_example(cfg: dict) -> int:
+    """Logits one example makes: the reference's blocking rules size a
+    pass by them."""
+    return int(cfg["classes"])
 
 
 def train_flops_per_example(cfg: dict) -> float:

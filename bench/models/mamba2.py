@@ -6,17 +6,24 @@ head over the vocabulary.  Straight ``jax.numpy``; nothing of the program.
 The mixer: one input projection to (z, x, B, C, dt); a depthwise causal
 convolution of ``d_conv`` taps with SiLU over (x, B, C); dt = softplus(dt
 + dt_bias); A = −exp(A_log); the selective state space recurrence, token
-by token (no chunking, so it shares no algorithm with the chunked scan),
+by token (no SSD chunking, so it shares no algorithm with the chunked
+scan),
 
     h_t = exp(dt_t·A)·h_{t−1} + dt_t·x_t ⊗ B_t,   y_t = h_t·C_t + D·x_t,
 
 then a gated RMSNorm ``rmsnorm(y · silu(z))`` and the output projection.
+The positions are scanned in blocks of about √S, each block under
+``jax.checkpoint``, so the backward pass keeps one state a block and
+recomputes the block's states, not one state a token; the arithmetic is
+the same token-by-token recurrence.  The layers are scanned, each under
+``jax.checkpoint``: the backward pass keeps each layer's input and
+recomputes one layer at a time.
 
-Classification rides on next-token prediction: each example's first
-``seq_len`` features are binned into ``vocab`` ids by
-``floor((tanh(x/4) + 1)/2 · vocab)`` (clipped), the targets are the next
-tokens with the class id last, and accuracy reads the last position's
-argmax over the class ids.
+The task is next-token prediction on token text (the configuration's
+``data`` kind ``markov_tokens``, ``workload.make_data``): each example is
+``seq_len + 1`` tokens, the inputs are its first ``seq_len`` and the
+targets its last ``seq_len``; the loss is the token cross-entropy and the
+accuracy top-1 next-token accuracy over every position.
 
 Weights come from the row seed as the configuration documents: from
 ``jax.random.key(seed)`` eight keys (embedding 0, head 1, layers 2); the
@@ -31,7 +38,6 @@ import math
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 VOCAB_PAD = 128
 
@@ -87,31 +93,60 @@ def init(cfg: dict, seed: int):
     }
 
 
-def tokenize(cfg: dict, x: np.ndarray, y: np.ndarray):
-    """(tokens, next-token targets) of int32, binned in float64."""
-    vocab, seq = int(cfg["vocab"]), int(cfg["seq_len"])
-    xs = np.asarray(x[:, :seq], np.float64)
-    bins = np.floor((np.tanh(xs / 4.0) + 1.0) * 0.5 * vocab)
-    tok = np.clip(bins, 0, vocab - 1).astype(np.int32)
-    lab = np.concatenate([tok[:, 1:], np.asarray(y, np.int32)[:, None]], 1)
-    return tok, lab
-
-
 def train_inputs(cfg: dict, data):
-    """Per-example arrays a training batch gathers: (tokens, targets)."""
-    tok, lab = tokenize(cfg, data.x, data.y)
-    return jnp.asarray(tok), jnp.asarray(lab)
+    """Per-example arrays a batch gathers: (inputs, next-token targets),
+    the first and the last ``seq_len`` tokens of each example."""
+    kind = (cfg.get("data") or {}).get("kind")
+    if kind != "markov_tokens":
+        raise ValueError(f"the Mamba-2 reference trains on token text "
+                         f"(data kind 'markov_tokens'), not {kind!r}")
+    seq = int(cfg["seq_len"])
+    x = jnp.asarray(data.x, jnp.int32)
+    return x[:, :seq], x[:, 1:seq + 1]
 
 
-def test_inputs(cfg: dict, data):
-    """(tokens, class labels) of the test split."""
-    tok, _ = tokenize(cfg, data.x, data.y)
-    return jnp.asarray(tok), jnp.asarray(data.y)
+test_inputs = train_inputs
 
 
 def _rmsnorm(x, scale, eps=1e-5):
     return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True)
                              + jnp.asarray(eps, x.dtype)) * scale
+
+
+def recurrence(A, xs, Bm, Cm, dt, prec):
+    """y_t = h_t·C_t with h_t = exp(dt_t·A)·h_{t−1} + dt_t·x_t ⊗ B_t, token
+    by token, from h_0 = 0.  ``xs`` is (B, S, H, P), ``Bm`` and ``Cm``
+    (B, S, G, N) with the heads split evenly over the G groups, ``dt``
+    (B, S, H).  The positions are scanned in blocks of ⌈√S⌉, each under
+    ``jax.checkpoint``; the last block is padded at its end, after every
+    real position, and its padded outputs dropped."""
+    b, S, H, P = xs.shape
+    G, N = Bm.shape[2], Bm.shape[3]
+    rep = H // G
+    L = math.isqrt(S - 1) + 1
+    nb = -(-S // L)
+
+    def step(state, t):
+        xt, bt, ct, dtt = t
+        bt, ct = jnp.repeat(bt, rep, 1), jnp.repeat(ct, rep, 1)  # (B,H,N)
+        state = (jnp.exp(dtt * A)[..., None, None] * state
+                 + (dtt[..., None] * xt)[..., None] * bt[:, :, None, :])
+        yt = jnp.einsum("bhpn,bhn->bhp", state, ct, precision=prec)
+        return state, yt
+
+    @jax.checkpoint
+    def run_block(state, blk):
+        return jax.lax.scan(step, state, blk)
+
+    def blocks(a):
+        a = jnp.moveaxis(a, 1, 0)
+        a = jnp.pad(a, [(0, nb * L - S)] + [(0, 0)] * (a.ndim - 1))
+        return a.reshape((nb, L) + a.shape[1:])
+
+    state0 = jnp.zeros((b, H, P, N), xs.dtype)
+    _, y = jax.lax.scan(run_block, state0,
+                        tuple(blocks(a) for a in (xs, Bm, Cm, dt)))
+    return jnp.moveaxis(y.reshape((nb * L,) + y.shape[2:])[:S], 0, 1)
 
 
 def _mixer(mp, h, s, prec):
@@ -129,22 +164,9 @@ def _mixer(mp, h, s, prec):
     xs = conv[..., :d_in].reshape(B, S, s["h"], s["p"])
     Bm = conv[..., d_in:d_in + g * n].reshape(B, S, g, n)
     Cm = conv[..., d_in + g * n:].reshape(B, S, g, n)
-    rep = s["h"] // g
-    Bm, Cm = jnp.repeat(Bm, rep, 2), jnp.repeat(Cm, rep, 2)   # (B,S,H,N)
     dt = jax.nn.softplus(dt + mp["dt_bias"])                  # (B,S,H)
     A = -jnp.exp(mp["A_log"])
-
-    def step(state, t):
-        xt, bt, ct, dtt = t
-        state = (jnp.exp(dtt * A)[..., None, None] * state
-                 + (dtt[..., None] * xt)[..., None] * bt[:, :, None, :])
-        yt = jnp.einsum("bhpn,bhn->bhp", state, ct, precision=prec)
-        return state, yt
-
-    state0 = jnp.zeros((B, s["h"], s["p"], n), h.dtype)
-    seq = tuple(jnp.moveaxis(a, 1, 0) for a in (xs, Bm, Cm, dt))
-    _, y = jax.lax.scan(step, state0, seq)
-    y = jnp.moveaxis(y, 0, 1) + xs * mp["D"][:, None]
+    y = recurrence(A, xs, Bm, Cm, dt, prec) + xs * mp["D"][:, None]
     y = y.reshape(B, S, d_in)
     y = _rmsnorm(y * jax.nn.silu(z), mp["norm"]["scale"])
     return jnp.matmul(y, mp["out_proj"], precision=prec)
@@ -152,30 +174,48 @@ def _mixer(mp, h, s, prec):
 
 def logits(params, tok, cfg, prec):
     s = sizes(cfg)
-    x = params["embed"]["table"][tok]
-    for i in range(s["layers"]):
-        lp = jax.tree_util.tree_map(lambda a: a[i], params["layers"])
-        x = x + _mixer(lp["mixer"], _rmsnorm(x, lp["ln"]["scale"]), s,
-                       prec)
+
+    @jax.checkpoint
+    def layer(x, lp):
+        return x + _mixer(lp["mixer"], _rmsnorm(x, lp["ln"]["scale"]), s,
+                          prec), None
+
+    x, _ = jax.lax.scan(layer, params["embed"]["table"][tok],
+                        params["layers"])
     x = _rmsnorm(x, params["final_norm"]["scale"])
     return jnp.matmul(x, params["lm_head"], precision=prec)[..., :s["vocab"]]
 
 
-def loss(params, batch, w, cfg, prec):
-    """Token cross-entropy, each sequence weighted by its example weight:
-    Σ w·nll / max(Σ w, 1e-6) over every position."""
+def nll_sums(params, batch, w, cfg, prec):
+    """(Σ w·nll, Σ w) over every position, each sequence weighted by its
+    example weight: the two sums that blocks of a batch add up to, before
+    ``weighted_mean`` divides them once."""
     tok, lab = batch
     z = logits(params, tok, cfg, prec)
     nll = jax.nn.logsumexp(z, -1) - jnp.take_along_axis(
         z, lab[..., None], -1)[..., 0]
     wt = jnp.broadcast_to(w[:, None], nll.shape).astype(nll.dtype)
-    return jnp.sum(nll * wt) / jnp.maximum(jnp.sum(wt), 1e-6)
+    return jnp.sum(nll * wt), jnp.sum(wt)
 
 
-def accuracy(params, batch, cfg, prec):
-    tok, y = batch
-    z = logits(params, tok, cfg, prec)[:, -1, :int(cfg["classes"])]
-    return jnp.mean((jnp.argmax(z, -1) == y).astype(jnp.float32))
+def weighted_mean(s, n):
+    """Σ w·nll / max(Σ w, 1e-6): the token cross-entropy."""
+    return s / jnp.maximum(n, 1e-6)
+
+
+def hits(params, batch, cfg, prec):
+    """(correct top-1 next-token predictions, predictions made) over every
+    position of a batch."""
+    tok, lab = batch
+    right = (jnp.argmax(logits(params, tok, cfg, prec), -1) == lab).astype(
+        jnp.float32)
+    return jnp.sum(right), right.size
+
+
+def logits_per_example(cfg: dict) -> int:
+    """Logits one sequence makes (positions × vocabulary): the reference's
+    blocking rules size a pass by them."""
+    return int(cfg["seq_len"]) * int(cfg["vocab"])
 
 
 def train_flops_per_example(cfg: dict) -> float:

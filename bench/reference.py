@@ -22,10 +22,21 @@ trains period by period in plain ``jax.numpy``:
 ``dtype`` float32 computes at ``highest`` matmul precision (the
 reference); bfloat16 keeps weights, residuals and every intermediate in
 bfloat16 (the control).
+
+The plain model is the file under ``models/`` that the configuration's
+``reference`` names, or ``models/<model_family>.py`` where it names none
+(``config_module``).  It gives ``init``, ``train_inputs``,
+``test_inputs``, ``nll_sums`` (Σ w·nll and Σ w of a batch),
+``weighted_mean``, ``hits`` (correct predictions and predictions made),
+``logits_per_example`` and ``train_flops_per_example``.  A pass that
+would hold more than ``BLOCK_BYTES`` is computed in blocks whose sums
+are divided once (``make_trajectory``); at the sizes where every pass
+fits, nothing is blocked.
 """
 from __future__ import annotations
 
 import importlib.util
+from dataclasses import dataclass
 from pathlib import Path
 from typing import Dict, List
 
@@ -36,17 +47,78 @@ import numpy as np
 MODELS_DIR = Path(__file__).resolve().parent / "models"
 tree_map = jax.tree_util.tree_map
 
+# the most one pass of the reference holds at once, by ``make_trajectory``'s
+# blocking rules; a pass that fits runs whole
+BLOCK_BYTES = 1 << 30
+F32 = 4
 
-def model_module(family: str):
-    """The reference model of a family, ``models/<family>.py``."""
-    path = MODELS_DIR / f"{family}.py"
+
+def model_module(name: str, models_dir: Path = MODELS_DIR):
+    """The reference model ``<models_dir>/<name>.py``."""
+    path = models_dir / f"{name}.py"
     if not path.is_file():
         raise ValueError(f"no reference model {path}")
-    spec = importlib.util.spec_from_file_location(f"bench_model_{family}",
+    spec = importlib.util.spec_from_file_location(f"bench_model_{name}",
                                                   path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+
+def config_module(cfg: dict, models_dir: Path = MODELS_DIR):
+    """The reference model a configuration names by ``reference``, or by
+    its ``model_family`` where it names none."""
+    return model_module(cfg.get("reference") or cfg["model_family"],
+                        models_dir)
+
+
+def block_rows(n: int, item_bytes: int, block_bytes: int) -> int:
+    """Items one pass over ``n`` takes at a time: all ``n`` where they fit
+    in ``block_bytes``, else the largest divisor of ``n`` whose items fit
+    (at least 1), so that every block has the same shape and none is
+    padded."""
+    if n * item_bytes <= block_bytes:
+        return n
+    cap = max(1, block_bytes // item_bytes)
+    return max(d for d in range(1, min(cap, n) + 1) if n % d == 0)
+
+
+def in_blocks(fn, arrays, rows: int):
+    """Σ over blocks of ``rows`` leading items of ``fn(block)`` (a pytree
+    of sums), one block at a time; ``fn(arrays)`` where one block holds
+    them all."""
+    n = jax.tree_util.tree_leaves(arrays)[0].shape[0]
+    if rows == n:
+        return fn(arrays)
+    blocks = tree_map(lambda a: a.reshape((n // rows, rows) + a.shape[1:]),
+                      arrays)
+    zero = tree_map(lambda a: jnp.zeros(a.shape, a.dtype), jax.eval_shape(
+        fn, tree_map(lambda a: a[0], blocks)))
+
+    def body(acc, blk):
+        return tree_map(jnp.add, acc, fn(blk)), None
+
+    return jax.lax.scan(body, zero, blocks)[0]
+
+
+@dataclass(frozen=True)
+class Blocks:
+    """How ``make_trajectory`` splits each pass of a period."""
+    in_turn: bool       # the cohort's gradients one client at a time
+    client_rows: int    # examples of one client's gradient a block
+    loss_rows: int      # examples of the loss pass after the update a block
+    test_rows: int      # examples of the test-accuracy pass a block
+
+
+def blocks_for(ex_bytes: int, cohort: int, slot: int, n_params: int,
+               n_test: int, block_bytes: int = BLOCK_BYTES) -> Blocks:
+    """The blocking rules, from the sizes a trajectory sees: ``ex_bytes``
+    of logits an example, ``cohort`` clients a period of ``slot`` examples
+    each, ``n_params`` float32 parameters, ``n_test`` test examples."""
+    return Blocks(in_turn=cohort * n_params * F32 > block_bytes,
+                  client_rows=block_rows(slot, ex_bytes, block_bytes),
+                  loss_rows=block_rows(cohort * slot, ex_bytes, block_bytes),
+                  test_rows=block_rows(n_test, ex_bytes, block_bytes))
 
 
 def sbc(t, ratio: float):
@@ -79,52 +151,110 @@ def cohorts(active: np.ndarray) -> np.ndarray:
 
 
 def make_trajectory(model, cfg: dict, ratio: float, compress: bool,
-                    dtype):
+                    dtype, block_bytes: int = BLOCK_BYTES):
     """A jitted ``(params0, plan, train, test) → (losses, accs, params,
-    first_agg_norms)`` of one row."""
+    first_agg_norms)`` of one row.
+
+    Each pass is computed in blocks where it would not fit in
+    ``block_bytes`` (``blocks_for``, by the sizes the trajectory sees and
+    ``model.logits_per_example`` float32 logits an example):
+
+    * the cohort's gradients are taken all at once (``vmap``) where the S
+      clients' gradients fit, else one client at a time, each client's
+      SBC and residual update in turn and the B_k-weighted sum carried;
+    * a client's gradient is one pass where its examples' logits fit, else
+      Σ over blocks of ∇(Σ w·nll), divided once by Σ w;
+    * the loss after the update and the test accuracy sum the weighted
+      nll and the correct predictions over blocks of examples where their
+      logits do not fit, and divide once.
+    """
     prec = (jax.lax.Precision.HIGHEST if dtype == jnp.float32
             else jax.lax.Precision.DEFAULT)
+    ex_bytes = F32 * int(model.logits_per_example(cfg))
 
-    def client_grad(params, batch, w):
-        return jax.grad(model.loss)(params, batch, w, cfg, prec)
+    def loss_of(params, batch, w):
+        return model.weighted_mean(*model.nll_sums(params, batch, w, cfg,
+                                                   prec))
 
-    def period(carry, xs, train, test):
-        params, residual = carry
-        ids = xs["cohort"]                               # (S,)
-        idx = xs["idx"][ids]                             # (S, slot)
-        w = xs["weight"][ids].astype(dtype)
-        bk = xs["batch"][ids].astype(dtype)
-        batch = tuple(a[idx] for a in train)
-        grads = jax.vmap(client_grad, in_axes=(None, 0, 0))(params, batch,
-                                                            w)
+    def client_grad(params, batch, w, rows):
+        if rows == w.shape[0]:
+            return jax.grad(loss_of)(params, batch, w)
+
+        def block(blk):
+            b, wb = blk
+            return jax.grad(lambda p: model.nll_sums(p, b, wb, cfg, prec),
+                            has_aux=True)(params)
+
+        g, n = in_blocks(block, (batch, w), rows)
+        return tree_map(lambda a: model.weighted_mean(a, n), g)
+
+    def cohort_all(params, residual, ids, batch, w, c, rows):
+        grads = jax.vmap(lambda b, wk: client_grad(params, b, wk, rows))(
+            batch, w)
         if compress:
             acc = tree_map(lambda g, r: g + r[ids], grads, residual)
             grads = tree_map(
                 lambda a: jax.vmap(lambda t: sbc(t, ratio))(a), acc)
             residual = tree_map(lambda r, a, s: r.at[ids].set(a - s),
                                 residual, acc, grads)
+        agg = tree_map(lambda g: jnp.tensordot(c, g, axes=1), grads)
+        return agg, residual
+
+    def cohort_in_turn(params, residual, ids, batch, w, c, rows):
+        def client(carry, xs):
+            agg, residual = carry
+            k, b, wk, ck = xs
+            g = client_grad(params, b, wk, rows)
+            if compress:
+                acc = tree_map(lambda a, r: a + r[k], g, residual)
+                g = tree_map(lambda a: sbc(a, ratio), acc)
+                residual = tree_map(lambda r, a, s: r.at[k].set(a - s),
+                                    residual, acc, g)
+            return (tree_map(lambda s, a: s + ck * a, agg, g),
+                    residual), None
+
+        zero = tree_map(jnp.zeros_like, params)
+        return jax.lax.scan(client, (zero, residual), (ids, batch, w, c))[0]
+
+    def period(carry, xs, train, test, blocks):
+        params, residual = carry
+        ids = xs["cohort"]                               # (S,)
+        idx = xs["idx"][ids]                             # (S, slot)
+        w = xs["weight"][ids].astype(dtype)
+        bk = xs["batch"][ids].astype(dtype)
+        batch = tuple(a[idx] for a in train)
         den = jnp.where(xs["aggden"] > 0, xs["aggden"].astype(dtype),
                         jnp.sum(bk))
-        agg = tree_map(lambda g: jnp.tensordot(bk / den, g, axes=1), grads)
+        cohort = cohort_in_turn if blocks.in_turn else cohort_all
+        agg, residual = cohort(params, residual, ids, batch, w, bk / den,
+                               blocks.client_rows)
         params = tree_map(lambda p, g: p - xs["lr"].astype(dtype) * g,
                           params, agg)
         flat = tuple(a.reshape((-1,) + a.shape[2:]) for a in batch)
-        loss = model.loss(params, flat, w.reshape(-1), cfg, prec)
-        acc_ = model.accuracy(params, test, cfg, prec)
+        loss = model.weighted_mean(*in_blocks(
+            lambda b: model.nll_sums(params, b[0], b[1], cfg, prec),
+            (flat, w.reshape(-1)), blocks.loss_rows))
+        right, made = in_blocks(lambda b: model.hits(params, b, cfg, prec),
+                                test, blocks.test_rows)
         norms = tree_map(lambda g: jnp.sqrt(jnp.sum(
             jnp.square(g.astype(jnp.float32)))), agg)
         return (params, residual), (loss.astype(jnp.float32),
-                                    acc_.astype(jnp.float32), norms)
+                                    (right / made).astype(jnp.float32),
+                                    norms)
 
     @jax.jit
     def run(params0, plan, train, test):
         params0 = tree_map(lambda a: a.astype(dtype), params0)
         k = plan["batch"].shape[1]
-        residual = tree_map(lambda p: jnp.zeros((k,) + p.shape, dtype),
-                            params0)
+        blocks = blocks_for(
+            ex_bytes, plan["cohort"].shape[1], plan["idx"].shape[-1],
+            sum(p.size for p in jax.tree_util.tree_leaves(params0)),
+            jax.tree_util.tree_leaves(test)[0].shape[0], block_bytes)
+        residual = (tree_map(lambda p: jnp.zeros((k,) + p.shape, dtype),
+                             params0) if compress else None)
         (params, _), (losses, accs, norms) = jax.lax.scan(
-            lambda c, x: period(c, x, train, test), (params0, residual),
-            plan)
+            lambda c, x: period(c, x, train, test, blocks),
+            (params0, residual), plan)
         first = tree_map(lambda n: n[0], norms)
         return losses, accs, tree_map(lambda a: a.astype(jnp.float32),
                                       params), first

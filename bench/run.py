@@ -5,7 +5,11 @@
 
 From the root of a checkout that holds the program (``src/repro``).  The
 cell, its configuration, traffic and limits are found by name from
-``BENCHMARK.json`` (``workload.find_cell``).
+``BENCHMARK.json`` (``workload.find_cell``).  A configuration's optional
+``spec`` (extra ``ScenarioSpec`` keywords; absent: none), ``data`` (what
+the data is drawn as; absent: Gaussian class clusters) and ``reference``
+(the plain model under ``bench/models/``; absent: the one named by
+``model_family``) are read as ``workload``'s docstring says.
 
 Set-up (counted in ``setup_s``, from process start): data and every row
 seed from ``--seed``, JAX's persistent compile cache inside the checkout,
@@ -126,7 +130,10 @@ def main(argv=None) -> int:
         peak = counts.peaks(devices[0].device_kind)
     except KeyError as e:
         fail(3, str(e))
-    out, lines = run_cell(cell, args, devices, peak)
+    try:
+        out, lines = run_cell(cell, args, devices, peak)
+    except workload.CellError as e:
+        fail(2, str(e))
     for line in lines:
         print(line, file=sys.stderr)
     sys.stderr.flush()

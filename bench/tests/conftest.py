@@ -24,6 +24,12 @@ TINY = {
                  "hidden": 32},
 }
 TINY_TRAFFIC = {"seeds_per_spec": 1, "periods": 3, "reference_rows": 8}
+# a toy Mamba-2 language model on token text, for the CPU
+TOY_LM = {"model_family": "mamba2", "hidden": 16, "expand": 2, "d_state": 4,
+          "head_dim": 4, "n_groups": 2, "d_conv": 4, "vocab": 40,
+          "seq_len": 10, "depth": 2, "n_train": 24, "n_test": 12,
+          "data": {"kind": "markov_tokens", "topics": 2, "branch": 3,
+                   "zipf": 1.0}}
 
 
 def make_root(tmp: Path, cell: str, cfg_over=None, traffic_over=None):
@@ -48,17 +54,17 @@ def make_root(tmp: Path, cell: str, cfg_over=None, traffic_over=None):
 def add_cell(root: Path, name: str, config: dict, traffic: dict,
              limits: dict):
     """Register a cell in a copied checkout the way a later PR would: a
-    configuration file, a traffic file, a limits file and two
-    ``BENCHMARK.json`` entries.  No code is edited."""
+    configuration file where the configuration is new, a traffic file, a
+    limits file and the ``BENCHMARK.json`` entries.  No code is edited."""
     bench = json.loads((root / "BENCHMARK.json").read_text())
     cfg_name, traffic_name = name.split(".", 1)
     cfg_file = f"bench/configs/{cfg_name}.json"
-    (root / cfg_file).write_text(json.dumps(config))
     (root / "bench" / "traffic" / f"{traffic_name}.json").write_text(
         json.dumps(traffic))
     (root / "bench" / "limits" / f"{name}.json").write_text(
         json.dumps(limits))
     if cfg_name not in {c["name"] for c in bench["configs"]}:
+        (root / cfg_file).write_text(json.dumps(config))
         bench["configs"].append({"name": cfg_name, "source": "test",
                                  "file": cfg_file, "reduced": [],
                                  "why": "test"})

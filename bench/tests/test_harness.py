@@ -1,15 +1,19 @@
 """Cell discovery by name, the client-period count, and the runs that
 must fail without printing a result."""
+import hashlib
 import json
 import os
 import shutil
 import subprocess
 import sys
+from pathlib import Path
 
+import numpy as np
 import pytest
 
+import reference
 import workload
-from conftest import BENCH, ROOT, TINY, TINY_TRAFFIC, add_cell
+from conftest import BENCH, ROOT, TINY, TINY_TRAFFIC, TOY_LM, add_cell
 
 
 def test_every_workload_resolves():
@@ -21,25 +25,62 @@ def test_every_workload_resolves():
         assert cell.per_layer
 
 
-def test_new_files_add_a_cell(tmp_path):
+def _digests(root):
+    return {p.relative_to(root): hashlib.sha256(p.read_bytes()).hexdigest()
+            for p in sorted(root.rglob("*")) if p.is_file()}
+
+
+def _mlp_case(tmp_path, tr):
+    tr.update(partitions=["iid"], policies=["full"], periods=7)
+    cfg = json.loads((BENCH / "configs" / "feel_mlp.json").read_text())
+    return "feel_mlp.gpu6_iid_full", cfg, {"loss_gap": 1, "ledger_faults": 0}
+
+
+def _language_model_case(tmp_path, tr):
+    """A toy language model with a reference file of its own, token text
+    and ScenarioSpec keywords passed through ``spec``."""
+    models = tmp_path / "bench" / "models"
+    (models / "toy_lm.py").write_text((models / "mamba2.py").read_text())
+    tr.update(TINY_TRAFFIC, partitions=["noniid"], policies=["full"])
+    cfg = dict(TOY_LM, reference="toy_lm", compression=0.01, b_max=4,
+               lr_ref_batch=4, spec={"local_steps": 1, "scheme": "feel"})
+    return "toy_lm.gpu6_full", cfg, {"loss_gap_median": 0.01,
+                                     "ledger_faults": 0}
+
+
+@pytest.mark.parametrize("case", [_mlp_case, _language_model_case],
+                         ids=["mlp", "language_model"])
+def test_new_files_add_a_cell(tmp_path, case):
     """A cell is files plus BENCHMARK.json entries: no code is edited."""
     shutil.copy(ROOT / "BENCHMARK.json", tmp_path)
     shutil.copytree(BENCH, tmp_path / "bench",
                     ignore=shutil.ignore_patterns("__pycache__"))
+    before = _digests(tmp_path)
     tr = json.loads((BENCH / "traffic" / "fig45_gpu6.json").read_text())
-    tr.update(partitions=["iid"], policies=["full"], periods=7)
-    cfg = json.loads((BENCH / "configs" / "feel_mlp.json").read_text())
-    add_cell(tmp_path, "feel_mlp.gpu6_iid_full", cfg, tr,
-             {"loss_gap": 1, "ledger_faults": 0})
+    name, cfg, limits = case(tmp_path, tr)
+    add_cell(tmp_path, name, cfg, tr, limits)
+    after = _digests(tmp_path)
+    assert {p for p in before if before[p] != after[p]} == {
+        Path("BENCHMARK.json")}
     with pytest.raises(workload.CellError):
-        workload.find_cell(ROOT, "feel_mlp.gpu6_iid_full")
-    cell = workload.find_cell(tmp_path, "feel_mlp.gpu6_iid_full",
-                              tmp_path / "bench")
-    assert cell.traffic["periods"] == 7
-    assert cell.config["model_family"] == "feel_mlp"
-    specs = workload.make_specs(cell.config, cell.traffic, 3)
-    assert [(s.partition, s.policy, s.k) for s in specs] == [
-        ("iid", "full", 6)]
+        workload.find_cell(ROOT, name)
+    cell = workload.find_cell(tmp_path, name, tmp_path / "bench")
+    assert cell.traffic["periods"] == tr["periods"]
+    assert cell.config == cfg
+    (spec,) = workload.make_specs(cell.config, cell.traffic, 3)
+    assert (spec.partition, spec.policy, spec.k) == (
+        tr["partitions"][0], "full", 6)
+    assert spec.model_family == cfg["model_family"]
+    models = tmp_path / "bench" / "models"
+    model = reference.config_module(cell.config, models)
+    ref = cfg.get("reference", cfg["model_family"])
+    assert model.__file__ == str(models / f"{ref}.py")
+    if "spec" in cfg:
+        assert (spec.local_steps, spec.scheme) == (1, "feel")
+        train, _ = workload.make_data(cell.config, 2**35 + 1)
+        assert train.x.shape == (24, 11) and train.x.dtype == np.int32
+        tok, lab = model.train_inputs(cell.config, train)
+        np.testing.assert_array_equal(tok[:, 1:], lab[:, :-1])
 
 
 def test_seeds_are_large_and_repeatable():

@@ -7,6 +7,22 @@ horizon and executor, and its limits file (``limits/<cell>.json``) the
 limits of the numbers that decide ``correct``.  Nothing here names a cell:
 a new cell is new files plus a ``BENCHMARK.json`` entry.
 
+A configuration file may hold three optional keys, so that a model
+configuration needs no edit here either.  Where all three are absent,
+every function computes what it computes for the MLP configuration:
+
+* ``spec``: keyword arguments added to every ``ScenarioSpec`` of the cell
+  (JSON arrays as tuples, objects as dicts; the program decides what it
+  accepts).  A keyword the harness sets itself (``HARNESS_SPEC_KEYS``, and
+  ``hidden``/``depth`` where the configuration has them) or one that
+  ``ScenarioSpec`` rejects is a ``CellError``.  Absent: none added.
+* ``data``: what ``make_data`` draws, ``{"kind": "clusters"}`` (Gaussian
+  class clusters) or ``{"kind": "markov_tokens", "topics": T, "branch":
+  F, "zipf": s}`` (next-token text at the configuration's ``vocab`` and
+  ``seq_len``).  Absent: clusters.
+* ``reference``: the file under ``models/`` that holds the plain model
+  (``reference.config_module``).  Absent: ``models/<model_family>.py``.
+
 The program is driven only through its public entry point,
 ``Experiment(data, test, specs).run(periods, executor=...)`` with the
 program's own executor that the traffic names; ``Spans`` puts a host span
@@ -118,48 +134,119 @@ STREAM_DATA, STREAM_ROWS, STREAM_SAMPLE = 1, 2, 3
 
 
 # ---------------------------------------------------------------------------
-# data: Gaussian class clusters at the configuration's shape, on the device
+# data: drawn from the seed on the device, of the kind the configuration
+# names
 # ---------------------------------------------------------------------------
 
 
 @dataclass
 class Dataset:
-    x: np.ndarray          # (N, D) float32
-    y: np.ndarray          # (N,) int32
+    x: np.ndarray          # (N, D) float32 features, or (N, S+1) int32 tokens
+    y: np.ndarray          # (N,) int32 class or topic
 
 
 def make_data(config: dict, seed: int):
-    """Train and test splits drawn from ``seed``: ``classes`` Gaussian
-    centres of norm ``spread`` in ``input_dim`` dimensions, unit noise
-    around them (the shape of CIFAR-10: 3,072 features, 10 classes).
-    Drawn on the default device in one jitted call and copied to the
-    host once: the program takes host arrays."""
+    """Train and test splits drawn from ``seed``, of the kind the
+    configuration's ``data`` object names (``{"kind": "clusters"}`` where
+    it has none): ``clusters`` (``_clusters``) or ``markov_tokens``
+    (``_markov_tokens``).  Drawn on the default device in one jitted call
+    and copied to the host once: the program takes host arrays."""
+    import jax
+
+    data = config.get("data") or {"kind": "clusters"}
+    draw = DATA_KINDS.get(data.get("kind"))
+    if draw is None:
+        raise CellError(f"data kind {data.get('kind')!r} is none of "
+                        f"{sorted(DATA_KINDS)}")
+    n_train, n_test = int(config["n_train"]), int(config["n_test"])
+    (s,) = derived_seeds(seed, 1, STREAM_DATA)
+    x, y = jax.device_get(draw(config, data, n_train + n_test,
+                               jax.random.key(s)))
+    x, y = np.asarray(x), np.asarray(y)
+    return (Dataset(x[:n_train], y[:n_train]),
+            Dataset(x[n_train:], y[n_train:]))
+
+
+def _clusters(config: dict, data: dict, n: int, key):
+    """``classes`` Gaussian centres of norm ``spread`` in ``input_dim``
+    dimensions, unit noise around them (the shape of CIFAR-10: 3,072
+    features, 10 classes)."""
     import jax
     import jax.numpy as jnp
 
-    n_train, n_test = int(config["n_train"]), int(config["n_test"])
     dim, classes = int(config["input_dim"]), int(config["classes"])
     spread = float(config["spread"])
-    (s,) = derived_seeds(seed, 1, STREAM_DATA)
 
     @jax.jit
     def draw(key):
         kc, ky, kx = jax.random.split(key, 3)
         centers = jax.random.normal(kc, (classes, dim)) * (spread
                                                            / dim ** 0.5)
-        y = jax.random.randint(ky, (n_train + n_test,), 0, classes)
-        x = centers[y] + jax.random.normal(kx, (n_train + n_test, dim))
+        y = jax.random.randint(ky, (n,), 0, classes)
+        x = centers[y] + jax.random.normal(kx, (n, dim))
         return x, y.astype(jnp.int32)
 
-    x, y = jax.device_get(draw(jax.random.key(s)))
-    x, y = np.asarray(x), np.asarray(y)
-    return (Dataset(x[:n_train], y[:n_train]),
-            Dataset(x[n_train:], y[n_train:]))
+    return draw(key)
+
+
+def _markov_tokens(config: dict, data: dict, n: int, key):
+    """Next-token text: ``n`` sequences of ``seq_len + 1`` ids in
+    [0, ``vocab``), each with a topic y uniform over ``topics``.  Every
+    (topic, token) has ``branch`` successor ids drawn from a Zipf law of
+    exponent ``zipf`` over the vocabulary (ranks mapped to ids by a seeded
+    permutation); a sequence starts at a uniform token, and each next
+    token is successor j of the current (topic, token), j drawn with
+    weights ∝ 1/(j+1).  Both draws are by inverse CDF.  Unigram counts
+    then fall off as in real text, and a client that holds two topics
+    (the §VI-A non-IID split by y) holds two topics' text."""
+    import jax
+    import jax.numpy as jnp
+
+    missing = {"topics", "branch", "zipf"} - set(data)
+    if missing:
+        raise CellError(f"markov_tokens data needs {sorted(missing)}")
+    vocab, seq = int(config["vocab"]), int(config["seq_len"])
+    topics, branch = int(data["topics"]), int(data["branch"])
+    zipf = float(data["zipf"])
+
+    def inverse_cdf(weights, u):
+        cdf = jnp.cumsum(weights) / jnp.sum(weights)
+        return jnp.minimum(jnp.searchsorted(cdf, u), weights.shape[0] - 1)
+
+    @jax.jit
+    def draw(key):
+        kp, ks, ky, k0, kj = jax.random.split(key, 5)
+        rank_w = 1.0 / jnp.arange(1, vocab + 1, dtype=jnp.float32) ** zipf
+        ids = jax.random.permutation(kp, vocab).astype(jnp.int32)
+        succ = ids[inverse_cdf(rank_w, jax.random.uniform(
+            ks, (topics, vocab, branch)))]
+        succ_w = 1.0 / jnp.arange(1, branch + 1, dtype=jnp.float32)
+        y = jax.random.randint(ky, (n,), 0, topics, jnp.int32)
+        first = jax.random.randint(k0, (n,), 0, vocab, jnp.int32)
+        picks = inverse_cdf(succ_w, jax.random.uniform(kj, (seq, n)))
+
+        def step(tok, j):
+            nxt = succ[y, tok, j]
+            return nxt, nxt
+
+        _, rest = jax.lax.scan(step, first, picks)
+        return jnp.concatenate([first[:, None], rest.T], 1), y
+
+    return draw(key)
+
+
+DATA_KINDS = {"clusters": _clusters, "markov_tokens": _markov_tokens}
 
 
 # ---------------------------------------------------------------------------
 # specs: the traffic file's grid, at the configuration's model
 # ---------------------------------------------------------------------------
+
+# ScenarioSpec keywords the harness sets from the traffic and configuration
+# itself; a configuration's ``spec`` may not set them again
+HARNESS_SPEC_KEYS = ("fleet", "name", "partition", "policy", "compress",
+                     "compression", "b_max", "base_lr", "seeds", "sampling",
+                     "model_family")
 
 
 def make_fleet(fleet: dict):
@@ -171,9 +258,21 @@ def make_fleet(fleet: dict):
                  for i in range(int(fleet["k"])))
 
 
+def _spec_value(v):
+    """A JSON value as a ScenarioSpec keyword takes it: arrays as tuples,
+    objects as dicts, strings and numbers as they are."""
+    if isinstance(v, list):
+        return tuple(_spec_value(a) for a in v)
+    if isinstance(v, dict):
+        return {k: _spec_value(a) for k, a in v.items()}
+    return v
+
+
 def make_specs(config: dict, traffic: dict, seed: int):
     """The cell's grid: one spec per (partition, policy), each carrying the
-    traffic's number of row seeds, drawn from ``seed``."""
+    traffic's number of row seeds, drawn from ``seed``.  ``hidden`` and
+    ``depth`` pass where the configuration has them, and the keywords of
+    its ``spec`` object as they are (none where it has none)."""
     from repro.api import ScenarioSpec
     from repro.topology import Sampling
 
@@ -181,19 +280,31 @@ def make_specs(config: dict, traffic: dict, seed: int):
     n_seeds = int(traffic["seeds_per_spec"])
     sampling = (None if traffic.get("sampling") is None
                 else Sampling(**traffic["sampling"]))
+    extra = {k: int(config[k]) for k in ("hidden", "depth") if k in config}
+    spec = config.get("spec") or {}
+    taken = sorted(set(spec) & (set(HARNESS_SPEC_KEYS) | set(extra)))
+    if taken:
+        raise CellError(f"the configuration's spec sets {taken}, which the "
+                        f"harness sets from the traffic and configuration")
+    extra.update((k, _spec_value(v)) for k, v in spec.items())
     cells = [(part, pol) for part in traffic["partitions"]
              for pol in traffic["policies"]]
     row_seeds = derived_seeds(seed, n_seeds * len(cells), STREAM_ROWS)
     specs = []
     for i, (part, pol) in enumerate(cells):
-        specs.append(ScenarioSpec(
-            fleet=fleet, name=traffic["fleet"]["name"], partition=part,
-            policy=pol, compress=bool(traffic["compress"]),
-            compression=float(config["compression"]),
-            b_max=int(config["b_max"]), base_lr=float(traffic["base_lr"]),
-            seeds=tuple(row_seeds[i * n_seeds:(i + 1) * n_seeds]),
-            hidden=int(config["hidden"]), depth=int(config["depth"]),
-            sampling=sampling, model_family=config["model_family"]))
+        try:
+            specs.append(ScenarioSpec(
+                fleet=fleet, name=traffic["fleet"]["name"], partition=part,
+                policy=pol, compress=bool(traffic["compress"]),
+                compression=float(config["compression"]),
+                b_max=int(config["b_max"]),
+                base_lr=float(traffic["base_lr"]),
+                seeds=tuple(row_seeds[i * n_seeds:(i + 1) * n_seeds]),
+                sampling=sampling, model_family=config["model_family"],
+                **extra))
+        except (TypeError, ValueError) as e:
+            raise CellError(f"ScenarioSpec rejects the configuration's spec "
+                            f"{sorted(spec)}: {e}") from e
     return specs
 
 
