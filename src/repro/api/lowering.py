@@ -222,9 +222,10 @@ def _partition(spec: ScenarioSpec, data, seed: int):
 def _n_params(spec: ScenarioSpec, input_dim: int, classes: int = 10) -> int:
     if spec.model_family != "feel_mlp":
         # the big-model families price the uplink at the true parameter
-        # count of the derived ArchConfig
+        # count of the spec's ArchConfig (named, cut to ``depth`` layers,
+        # or derived)
         return model_engine.family_n_params(
-            spec.model_family, spec.hidden, spec.depth)
+            spec.model_family, spec.hidden, spec.depth, spec.arch)
     dims = [input_dim] + [spec.hidden] * (spec.depth - 1) + [classes]
     return sum(i * o + o for i, o in zip(dims[:-1], dims[1:]))
 
@@ -236,7 +237,7 @@ def _init_params_batch(rows: Sequence[Row], input_dim: int):
     keys = jnp.stack([jax.random.key(r.seed) for r in rows])
     if spec.model_family != "feel_mlp":
         return model_engine.init_params_batch(
-            spec.model_family, spec.hidden, spec.depth, keys)
+            spec.model_family, spec.hidden, spec.depth, keys, spec.arch)
     return jax.vmap(lambda k: feel_model.init(
         k, spec.hidden, depth=spec.depth, input_dim=input_dim))(keys)
 
@@ -265,7 +266,8 @@ def _plan_key(r: Row) -> tuple:
     s = r.spec
     return (s.fleet, s.effective_policy, s.b_max, s.compression, s.cell,
             s.hidden, s.depth, r.seed, s.sampling, s.topology,
-            s.fading, s.faults, s.energy, s.adapt_tau, s.model_family)
+            s.fading, s.faults, s.energy, s.adapt_tau, s.model_family,
+            s.arch, s.seq_len)
 
 
 def _rescale_lr(horizon, base_lr: float, ref_batch: float):
@@ -699,7 +701,8 @@ def _dispatch_feel(plan: BucketPlan, data, test, mesh,
                 state, schedules, data, test,
                 model_family=spec0.model_family, hidden=spec0.hidden,
                 depth=spec0.depth, compress=spec0.compress,
-                ratio=spec0.compression, mesh=mesh, active=active)
+                ratio=spec0.compression, mesh=mesh, active=active,
+                arch=spec0.arch, seq_len=spec0.seq_len)
     else:
         state, (losses, accs, decays) = engine.resume_trajectory_batch(
             state, schedules, data, test,
@@ -751,7 +754,13 @@ def dispatch_bucket(plan: BucketPlan, data, test, mesh=None,
     carrying the plan's ids; where the period step runs
     ``compress_dense`` (the MLP family with SBC on) its stat
     ``sbc_count_passes`` counts the threshold search's passes over the
-    gradients per client-period, summed over the model's leaves."""
+    gradients per client-period, summed over the model's leaves.  Where
+    the spec names an architecture, its stats are ``arch_params`` (the
+    cut architecture's parameter count; a counter is a number, since
+    trace readers sum them), ``seq_len``, ``tokens`` (Σ B_k × seq_len of
+    the participants), ``client_turns`` (steps of the client scan: rows
+    × periods × padded K) and ``logit_block`` (sequences of a client's
+    gradient a logit block holds)."""
     dispatcher = (_dispatch_feel if plan.bucket.kind == "feel"
                   else _dispatch_dev)
     with obs.span("repro.dispatch", **plan.ids) as sp:
@@ -763,7 +772,27 @@ def dispatch_bucket(plan: BucketPlan, data, test, mesh=None,
                                         depth=spec0.depth,
                                         input_dim=plan.input_dim)))
             sp.stat(sbc_count_passes=len(leaves) * sbc.count_passes())
+        if sp.on and spec0.arch is not None:
+            sp.stat(**_text_counters(plan))
         return dispatcher(plan, data, test, mesh, state=state)
+
+
+def _text_counters(plan: BucketPlan) -> dict:
+    """The ``repro.dispatch`` stats of a named architecture's bucket."""
+    spec0 = plan.bucket.rows[0].spec
+    schedules = plan.payload["schedules"]
+    n, periods = plan.times.shape
+    active = np.asarray(plan.payload["active"], np.float64)
+    if active.ndim == 2:
+        active = active[:, None, :]
+    batch = np.stack([s.batch for s in schedules])
+    return {"arch_params": _n_params(spec0, plan.input_dim),
+            "seq_len": spec0.seq_len,
+            "tokens": int((batch * active).sum()) * spec0.seq_len,
+            "client_turns": n * periods * plan.bucket.k_pad,
+            "logit_block": model_engine.logit_block(
+                spec0.model_family, spec0.hidden, spec0.depth, spec0.arch,
+                spec0.seq_len, schedules[0].idx.shape[-1])}
 
 
 # ---------------------------------------------------------------------------
@@ -840,10 +869,20 @@ def trace_bucket(plan: BucketPlan, data, test) -> TracedBucket:
             # lane, so it needs no certificate).
             active = engine._normalize_active_batch(
                 plan.payload["active"], n, periods, k_pad)
-            params0 = _init_params_batch(rows, plan.input_dim)
-            residual0 = tree_map(
-                lambda p: jnp.zeros((p.shape[0], k_pad) + p.shape[1:],
-                                    p.dtype), params0)
+            if spec0.arch is None:
+                params0 = _init_params_batch(rows, plan.input_dim)
+                residual0 = tree_map(
+                    lambda p: jnp.zeros((p.shape[0], k_pad) + p.shape[1:],
+                                        p.dtype), params0)
+            else:
+                # a named architecture at its published widths is traced
+                # from shapes: its weights and residuals are not built
+                params0 = jax.eval_shape(
+                    lambda: _init_params_batch(rows, plan.input_dim))
+                residual0 = tree_map(
+                    lambda p: jax.ShapeDtypeStruct(
+                        (p.shape[0], k_pad) + p.shape[1:], p.dtype),
+                    params0)
             member = plan.payload.get("member")
             xs = engine.stack_schedules(schedules)
             data_args = engine.host_to_device(
@@ -879,17 +918,17 @@ def trace_bucket(plan: BucketPlan, data, test) -> TracedBucket:
                     NO_LABEL, NO_LABEL, NO_LABEL, NO_LABEL)
                 n_leaves = len(jax.tree_util.tree_leaves(params_e0))
             elif spec0.model_family != "feel_mlp":
-                # big-model families trace against the tokenized datasets
-                # but share the MLP scan's label/contract story verbatim:
-                # the program's own masking must re-establish padding
-                # safety from variant schedule lanes
-                tok, lab = model_engine.tokenize(data)
-                test_tok, _ = model_engine.tokenize(test)
-                data_args = engine.host_to_device(
-                    (tok, lab, test_tok, np.asarray(test.y)))
+                # big-model families trace against the datasets as the
+                # program takes them but share the MLP scan's
+                # label/contract story verbatim: the program's own masking
+                # must re-establish padding safety from variant schedule
+                # lanes
+                data_args = engine.host_to_device(model_engine.program_data(
+                    data, test, spec0.arch, spec0.seq_len))
                 fn = model_engine.model_trajectory_program(
                     spec0.model_family, spec0.hidden, spec0.depth,
-                    spec0.compress, spec0.compression)
+                    spec0.compress, spec0.compression, arch=spec0.arch,
+                    seq_len=spec0.seq_len)
                 closed = jax.make_jaxpr(fn)(
                     params0, residual0, active, xs, *data_args)
                 labels = (
@@ -899,7 +938,7 @@ def trace_bucket(plan: BucketPlan, data, test) -> TracedBucket:
                     {"idx": LaneLabel(2), "weight": LaneLabel(2),
                      "batch": LaneLabel(2), "lr": NO_LABEL,
                      "aggden": NO_LABEL},
-                    NO_LABEL, NO_LABEL, NO_LABEL, NO_LABEL)
+                    *(NO_LABEL for _ in data_args))
                 n_leaves = len(jax.tree_util.tree_leaves(params0))
             else:
                 fn = engine.trajectory_program(

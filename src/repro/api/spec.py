@@ -22,9 +22,10 @@ trajectory matches:
   shapes;
 * ``local_steps``, ``compress`` and ``compression`` — scan-body structure
   (static python branching / top-k fraction inside the jitted step);
-* model architecture (``model_family``, ``hidden``, ``depth``) — the
-  per-device train step itself (MLP scan vs big-model transformer/mamba2
-  step) and the parameter pytree shapes;
+* model architecture (``model_family``, ``hidden``, ``depth``, and for a
+  named architecture ``arch`` and ``seq_len``) — the per-device train
+  step itself (MLP scan vs big-model transformer/mamba2 step) and the
+  parameter pytree shapes;
 * ``replan`` (FEEL family) — the closed-loop ξ re-plan interval: the
   horizon executes as ``replan``-period chunked scans with estimator
   feedback between chunks, and every row of a bucket must chunk on the
@@ -63,6 +64,9 @@ SCHEMES = ("feel", "gradient_fl", "model_fl", "individual")
 # is the paper's MLP scan; ``transformer`` / ``mamba2`` run the big-model
 # train step (fed/train_step.py) with the pallas kernels in the hot path.
 MODEL_FAMILIES = ("feel_mlp", "transformer", "mamba2")
+# The ``model_family`` path a named architecture (``ScenarioSpec.arch``, a
+# ``repro.configs.ARCHS`` entry) trains on, by its ``ArchConfig.family``.
+ARCH_FAMILIES = {"ssm": "mamba2"}
 # The dev-family schemes train full local epochs with a fixed per-device
 # batch; PR-1 capped it at 64 — kept as the lowering rule.
 DEV_EPOCH_BATCH_CAP = 64
@@ -93,6 +97,8 @@ class ScenarioSpec:
     energy: Optional[EnergyBudget] = None  # per-user per-period energy caps
     adapt_tau: Optional[TauAdapt] = None   # re-planned local-steps knob
     model_family: str = "feel_mlp"       # feel_mlp | transformer | mamba2
+    arch: Optional[str] = None           # a repro.configs.ARCHS name
+    seq_len: Optional[int] = None        # tokens a sequence (with arch)
 
     def __post_init__(self):
         object.__setattr__(self, "fleet", tuple(self.fleet))
@@ -186,6 +192,12 @@ class ScenarioSpec:
                     f"model_family={self.model_family!r} derives its "
                     f"ArchConfig from hidden={self.hidden}, which must be "
                     "divisible by 4 (attention heads / SSM head grouping)")
+        if self.arch is not None:
+            self._check_arch()
+        elif self.seq_len is not None:
+            raise ValueError(
+                "seq_len= is the token length of a named architecture's "
+                "text; set arch= too")
         if self.sampling is not None and self.sampling.weighted:
             if self.topology is not None:
                 raise ValueError(
@@ -196,6 +208,43 @@ class ScenarioSpec:
                     "weighted (1/p) sampling needs probabilistic "
                     "inclusion; deterministic energy drops break the "
                     "Horvitz-Thompson correction")
+
+    def _check_arch(self):
+        """With ``arch`` set, ``hidden`` is the architecture's d_model,
+        ``depth`` the number of its layers kept, and ``seq_len`` the
+        tokens of a training sequence."""
+        from repro.configs import ARCHS
+        arch = ARCHS.get(self.arch)
+        if arch is None:
+            raise ValueError(
+                f"arch {self.arch!r} is not in repro.configs.ARCHS "
+                f"({sorted(ARCHS)})")
+        family = ARCH_FAMILIES.get(arch.family)
+        if family is None:
+            raise ValueError(
+                f"arch {self.arch!r} is a {arch.family!r} architecture; "
+                f"model_family trains only {sorted(ARCH_FAMILIES)} ones")
+        if self.model_family != family:
+            raise ValueError(
+                f"model_family {self.model_family!r} must be {family!r}, "
+                f"the family path of arch {self.arch!r} ({arch.family})")
+        if self.hidden != arch.d_model:
+            raise ValueError(
+                f"hidden={self.hidden} must equal arch {self.arch!r}'s "
+                f"d_model {arch.d_model}")
+        if not 1 <= self.depth <= arch.n_layers:
+            raise ValueError(
+                f"depth={self.depth} (layers kept) must lie in "
+                f"[1, {arch.n_layers}] for arch {self.arch!r}")
+        if (not isinstance(self.seq_len, int)
+                or isinstance(self.seq_len, bool) or self.seq_len < 1):
+            raise ValueError(
+                f"seq_len must be a positive int with arch=, got "
+                f"{self.seq_len!r}")
+        if arch.ssm is not None and self.seq_len % arch.ssm.chunk:
+            raise ValueError(
+                f"seq_len={self.seq_len} must be divisible by arch "
+                f"{self.arch!r}'s SSD chunk {arch.ssm.chunk}")
 
     # ---- derived lowering attributes -------------------------------------
     @property
@@ -271,7 +320,8 @@ class ScenarioSpec:
         ``model_family`` is structural: the scan body is a different
         program per family (MLP scan vs the big-model train step on the
         pallas kernels), so a ``grid(base, model_family=[...])`` sweep
-        lowers to exactly one program per family-bucket."""
+        lowers to exactly one program per family-bucket; so are ``arch``
+        and ``seq_len``, the named architecture and its text's shape."""
         if self.is_dev_scheme:
             return ("dev", self.scheme, self.dev_epoch_batch,
                     self.hidden, self.depth)
@@ -282,7 +332,7 @@ class ScenarioSpec:
                 self.hidden, self.depth, self.replan, topo,
                 None if self.fading is None else self.fading.states,
                 None if self.adapt_tau is None else self.adapt_tau.choices,
-                self.model_family)
+                self.arch, self.seq_len, self.model_family)
 
 
 jax.tree_util.register_static(ScenarioSpec)

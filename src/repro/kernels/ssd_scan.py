@@ -57,7 +57,11 @@ def _ssd_kernel(x_ref, cc_ref, cr_ref, b_ref, c_ref, y_ref, state_ref):
                                 preferred_element_type=jnp.float32)
     y_off = y_off * jnp.exp(cs_c)                 # (l, P)
 
-    total = cs_r[:, l - 1:]                       # (1, 1) chunk decay
+    # the chunk's total decay, cs at its last position, taken as a (1, 1)
+    # masked sum: a lane slice at offset l - 1 has a layout Mosaic cannot
+    # broadcast once l fills a whole vreg row (l = 256)
+    last = jax.lax.broadcasted_iota(jnp.int32, (1, l), 1) == l - 1
+    total = jnp.sum(jnp.where(last, cs_r, 0.0), axis=1, keepdims=True)
     decay_out = jnp.exp(total - cs_c)             # (l, 1)
     upd = jax.lax.dot_general(xdt, Bm * decay_out,
                               (((0,), (0,)), ((), ())),

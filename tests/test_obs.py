@@ -24,7 +24,7 @@ from repro.api.lowering import group_rows
 from repro.compression import sbc
 from repro.core import DeviceProfile
 from repro.data.pipeline import ClassificationData
-from repro.fed import engine
+from repro.fed import engine, model_engine
 from repro.topology import Sampling
 
 # distinctive shapes so engine program caches never collide across modules
@@ -287,6 +287,96 @@ def test_period_step_carries_its_scopes_into_the_compiled_program(
         text = fn.lower(params0, residual0, active, xs,
                         *engine.host_to_device(
                             (data.x, data.y, test.x, test.y))
+                        ).compile().as_text()
+    names = [ln.split('op_name="', 1)[1].split('"', 1)[0]
+             for ln in text.splitlines() if 'op_name="' in ln]
+    for scope in SCOPES:
+        assert any(f"/{scope}/" in n for n in names), scope
+
+
+# ---------------------------------------------------------------------------
+# a named architecture on token text: its dispatch counters and its scopes
+# ---------------------------------------------------------------------------
+
+TEXT_ARCH, TEXT_SEQ, TEXT_VOCAB = "mamba2-obs-test", 16, 128
+
+
+@pytest.fixture(scope="module")
+def text_arch():
+    from repro.configs import ARCHS
+    from repro.configs.base import ArchConfig, SSMConfig
+    ARCHS[TEXT_ARCH] = ArchConfig(
+        name=TEXT_ARCH, family="ssm", n_layers=1, d_model=32, n_heads=0,
+        n_kv_heads=0, d_ff=0, vocab=TEXT_VOCAB, attn_kind="none",
+        ssm=SSMConfig(d_state=8, head_dim=16, chunk=8))
+    rng = np.random.default_rng(0)
+
+    def text(n):
+        return ClassificationData(
+            rng.integers(0, TEXT_VOCAB, (n, TEXT_SEQ + 1)).astype(np.int32),
+            rng.integers(0, 3, n).astype(np.int32))
+
+    yield text(48), text(4)
+    del ARCHS[TEXT_ARCH]
+
+
+def _text_spec(fleet, **kw):
+    return _spec(fleet, model_family="mamba2", arch=TEXT_ARCH, hidden=32,
+                 depth=1, seq_len=TEXT_SEQ, b_max=4, compression=0.1,
+                 seeds=(3,), **kw)
+
+
+def test_text_dispatch_counts_tokens_turns_and_blocks(tmp_path, fleet,
+                                                      text_arch,
+                                                      monkeypatch):
+    """``repro.dispatch`` of a named architecture carries ``arch_params``,
+    ``seq_len``, ``tokens`` (Σ B_k × seq_len of the participants),
+    ``client_turns`` (rows × periods × padded K) and ``logit_block``
+    (here forced to 2 of a client's 4 sequences)."""
+    data, test = text_arch
+    spec = _text_spec(fleet, sampling=Sampling(size=2))
+    monkeypatch.setattr(model_engine, "LOGIT_BLOCK_BYTES",
+                        2 * 4 * TEXT_SEQ * TEXT_VOCAB)
+    (bucket,) = group_rows([spec])
+    plan = lowering.plan_bucket(bucket, data, PERIODS)
+    active = np.asarray(plan.payload["active"], np.float64)
+    batch = np.stack([s.batch for s in plan.payload["schedules"]])
+    exp = Experiment(data, test, [spec])
+    _, evs = _profiled(tmp_path, lambda: exp.run(
+        PERIODS, executor=SerialExecutor()))
+    (d,) = _named(evs, "repro.dispatch")
+    # every counter is a number: trace readers sum them over spans
+    assert all(isinstance(v, (int, float)) for v in d.stats.values())
+    assert d.stats["arch_params"] == model_engine.family_n_params(
+        "mamba2", 32, 1, TEXT_ARCH)
+    assert d.stats["seq_len"] == TEXT_SEQ
+    assert d.stats["tokens"] == int((batch * active).sum()) * TEXT_SEQ
+    assert 0 < d.stats["tokens"] < PERIODS * 3 * 4 * TEXT_SEQ
+    assert d.stats["client_turns"] == PERIODS * bucket.k_pad
+    assert d.stats["logit_block"] == 2
+
+
+def test_text_period_carries_its_scopes_into_the_compiled_program(
+        fleet, text_arch):
+    """The client scan and the blocked passes keep the five scopes: each
+    reaches the op metadata of the compiled token-text program."""
+    data, test = text_arch
+    spec = _text_spec(fleet)
+    (bucket,) = group_rows([spec])
+    plan = lowering.plan_bucket(bucket, data, PERIODS)
+    params0 = lowering._init_params_batch(bucket.rows, TEXT_SEQ + 1)
+    residual0 = jax.tree_util.tree_map(
+        lambda p: jax.numpy.zeros((p.shape[0], bucket.k_pad) + p.shape[1:],
+                                  p.dtype), params0)
+    active = engine._normalize_active_batch(
+        plan.payload["active"], 1, PERIODS, bucket.k_pad)
+    xs = engine.stack_schedules(plan.payload["schedules"])
+    fn = model_engine.model_trajectory_program(
+        "mamba2", 32, 1, True, 0.1, arch=TEXT_ARCH, seq_len=TEXT_SEQ)
+    with engine.suspend_trace_count():
+        text = fn.lower(params0, residual0, active, xs,
+                        *engine.host_to_device(model_engine.program_data(
+                            data, test, TEXT_ARCH, TEXT_SEQ))
                         ).compile().as_text()
     names = [ln.split('op_name="', 1)[1].split('"', 1)[0]
              for ln in text.splitlines() if 'op_name="' in ln]

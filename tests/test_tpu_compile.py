@@ -19,6 +19,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.compression import sbc
+from repro.configs import get_arch
 from repro.fed import feel_model
 from repro.fed.model_engine import SEQ_CAP, family_arch
 from repro.kernels import ops
@@ -93,6 +94,29 @@ def test_flash_attention_grad_compiles(one_chip):
                               argnums=(0, 1, 2))
     text = _compiled_text(step, _attention_shapes(), one_chip)
     assert _n_kernels(text) >= 1
+
+
+def _published_ssd_shapes():
+    """Mamba-2 2.7B (``repro.configs``): 80 heads of 64 channels, one
+    128-state group, chunk 256, two 2,048-token sequences (a logit block
+    of the token-text path)."""
+    cfg = get_arch("mamba2-2.7b")
+    s = cfg.ssm
+    _, H, _ = mamba2_dims(cfg)
+    B, S = 2, 2048
+    return ([(B, S, H, s.head_dim), (B, S, H), (H,),
+             (B, S, s.n_groups, s.d_state), (B, S, s.n_groups, s.d_state)],
+            s.chunk)
+
+
+def test_ssd_forward_and_grad_compile_at_published_widths(one_chip):
+    shapes, chunk = _published_ssd_shapes()
+    fn = lambda *a: ops.ssd(*a, chunk=chunk, interpret=False)  # noqa: E731
+    text = _compiled_text(fn, shapes, one_chip)
+    assert _n_kernels(text) == 1 and _named(text, "ssd_scan")
+    step = jax.value_and_grad(lambda *a: jnp.sum(fn(*a)),
+                              argnums=tuple(range(5)))
+    assert _n_kernels(_compiled_text(step, shapes, one_chip)) == 1
 
 
 def test_ssd_forward_compiles(one_chip):
